@@ -16,7 +16,11 @@ artifact differs (the convention is a ``_q``/``_quick`` suffix in the tag),
 so a quick CI run never poisons a full local run or vice versa.
 
 CSV: ``emit_csv`` prints the scaffold's ``name,us_per_call,derived`` rows;
-``fmt_speedup`` renders invalid (over-budget) results as ``N/A``."""
+``fmt_speedup`` renders invalid (over-budget) results as ``N/A``.
+
+COMPILE CACHE: importing this module points JAX's persistent compilation
+cache at ``repro.compile_cache`` (``JAX_COMPILATION_CACHE_DIR`` when set,
+else ``.jax_cache/`` in the checkout)."""
 from __future__ import annotations
 
 import json
@@ -27,9 +31,12 @@ import time
 import jax
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core import (PAPER_ACCEL, DTConfig, FusionEnv, S2SConfig,
                         TrainConfig, collect_teacher_data, dt_init, dt_loss,
                         s2s_init, s2s_loss, train_model, merge_datasets)
+
+use_compile_cache()
 
 MB = float(2 ** 20)
 ART = pathlib.Path("artifacts/bench")

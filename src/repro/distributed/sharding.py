@@ -76,33 +76,9 @@ def shard_leading_axis(tree, mesh, *, axis: str = "data"):
 
 
 def ambient_mesh():
-    """The mesh currently in context (abstract or physical), or None.
-
-    ``get_abstract_mesh`` has moved between jax releases
-    (``jax.sharding`` <-> ``jax._src.mesh``); older versions only expose the
-    physical mesh entered via ``with mesh:`` through ``thread_resources``.
-    Model code must stay mesh-agnostic either way, so every probe degrades
-    to None instead of raising."""
-    try:
-        from jax._src import mesh as mesh_impl
-    except ImportError:
-        mesh_impl = None
-    get_am = getattr(jax.sharding, "get_abstract_mesh",
-                     getattr(mesh_impl, "get_abstract_mesh", None))
-    if get_am is not None:
-        try:
-            am = get_am()
-        except Exception:
-            am = None
-        if am is not None and getattr(am, "axis_names", ()) \
-                and not getattr(am, "empty", False):
-            return am
-    tr = getattr(mesh_impl, "thread_resources", None)
-    pm = getattr(getattr(tr, "env", None), "physical_mesh", None)
-    if pm is not None and getattr(pm, "axis_names", ()) \
-            and not getattr(pm, "empty", True):
-        return pm
-    return None
+    """The mesh set by ``jax.set_mesh`` in the current context, or None."""
+    am = jax.sharding.get_abstract_mesh()
+    return None if am.empty else am
 
 
 def logical_shard(x, *dims):

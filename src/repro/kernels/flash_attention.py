@@ -44,10 +44,8 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *, bq: int, bk: int, seq_k: int,
 
     def body(j, carry):
         m, l, acc = carry
-        k = pl.load(k_ref, (pl.dslice(j * bk, bk), slice(None))
-                    ).astype(jnp.float32)                   # [bk, hd]
-        v = pl.load(v_ref, (pl.dslice(j * bk, bk), slice(None))
-                    ).astype(jnp.float32)
+        k = k_ref[pl.ds(j * bk, bk), :].astype(jnp.float32)  # [bk, hd]
+        v = v_ref[pl.ds(j * bk, bk), :].astype(jnp.float32)
         s = q @ k.T                                         # [bq, bk]
         ids_q = q_lo + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
         ids_k = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)

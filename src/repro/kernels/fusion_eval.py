@@ -3,12 +3,15 @@ hot loop as a kernel, in production grid form (DESIGN §7, §13).
 
 G-Sampler evaluates thousands of strategies per generation across a whole
 (workload x accelerator x budget) condition grid; this kernel evaluates a
-``[bp, P]`` BLOCK of candidate strategies per grid step entirely in VMEM.
-The per-condition layer table (A/W/F/OE/UC/SKIP, padded to P positions) is
-resident in VMEM and shared by every candidate in the block; per-candidate
-group accumulators live in registers/VPU lanes, so the sweep over the P
-chain positions is a statically unrolled loop of [bp]-wide vector ops — no
-HBM traffic beyond one read of the strategy block and one write of the
+BLOCK of ``bp`` candidate strategies per grid step, laid out ``[P, bp]``
+with the candidates on the vector lanes.  The per-condition layer table
+(A/W/F/OE/UC/SKIP, padded to P positions), ``n`` and the [batch, BPE, hw]
+row live in SMEM, one condition per grid row: every per-position term is
+a scalar broadcast across the lanes, and the residual-source read is a
+dynamic scalar load.  Per-candidate group accumulators are [1, bp] vectors
+carried through a ``fori_loop`` over the P chain positions, and closed
+groups are written into the per-group output blocks in place — no HBM
+traffic beyond one read of the strategy block and one write of the
 per-group result matrices.
 
 THE ACCELERATOR IS TRACED DATA, not a compile-time constant: the hardware
@@ -25,8 +28,8 @@ vectors plus per-position group ids) accumulated in the same position order
 as ``cost_model._evaluate_full``'s sorted segment-sums, and the CostOut
 roofline/reduction step runs OUTSIDE the kernel through
 ``cost_model.finalize_groups`` — the same jnp expressions the XLA evaluator
-lowers.  On the CPU container (interpret mode, the ``kernels/ops.py``
-selection contract) the two backends are bit-identical, which is what lets
+lowers.  On the CPU (interpret mode, chosen from the platform) the two
+backends are bit-identical, which is what lets
 ``gsampler_search_grid`` produce the same teacher corpus on either
 ``evaluator`` backend.  The oracles are ``kernels/ref.fusion_eval_ref`` /
 ``fusion_eval_grid_ref`` and the loop-based ``core.ref_model``.
@@ -34,79 +37,84 @@ selection contract) the two backends are bit-identical, which is what lets
 from __future__ import annotations
 
 import functools
-import time
-import warnings
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..core import cost_model as _cm
 from ..core.accel import HW_FEATURE_DIM, hw_array, stack_hw
 
 __all__ = ["fusion_eval_population", "fusion_eval_population_stats",
-           "fusion_eval_grid", "fusion_eval_grid_stats",
-           "compiled_backend_supported", "autotune_block", "backend_stats"]
+           "fusion_eval_grid", "fusion_eval_grid_stats"]
 
 _UTIL_MIN = 1.0 / 4096.0
-# HW_FIELDS slots the kernel reads from its [C, HW_FEATURE_DIM] hw row
+# HW_FIELDS slots the kernel reads from the hw part of its condition row
 _NPE, _LANES, _FREQ = 0, 1, 2
 _BPE_SLOT, _STREAM = 6, 9
+# SMEM condition row: [batch, pack-time BPE, hw...]
+_COND_BATCH, _COND_BPE, _COND_HW = 0, 1, 2
+# SMEM layer table rows
+_TAB_ROWS = ("A", "W", "F", "OE", "UC")
+_A, _W, _F, _OE, _UC = range(len(_TAB_ROWS))
 
 
-def _fe_kernel(strat_ref, A_ref, W_ref, F_ref, OE_ref, UC_ref, SKIP_ref,
-               n_ref, batch_ref, bpe_ref, hw_ref,
+def _fe_kernel(tab_ref, skip_ref, n_ref, cond_ref, strat_ref,
                Cg_ref, Tg_ref, Og_ref, Mg_ref, Wg_ref, glen_ref, gid_ref,
                *, P: int):
-    """One [bp, P] strategy block of one condition row.
+    """One [P, bp] strategy block of one condition row (lanes = candidates).
+
+    The condition's layer table, skip sources, ``n`` and its [batch, BPE,
+    hw...] row are per-condition scalars in SMEM: every per-position term
+    is a scalar load broadcast across the candidate lanes, and the
+    residual-source read ``A[src]`` is a dynamic scalar load.
 
     Emits the group decomposition (per-group component sums indexed by
     group id, plus per-position group ids); latency/peak/validity are
     assembled outside by ``cost_model.finalize_groups`` so both evaluator
     backends share one reduction lowering (DESIGN §13)."""
-    bp = strat_ref.shape[1]
-    strat = strat_ref[...][0].astype(jnp.float32)         # [bp, P]
-    n = n_ref[...][0]
-    B = batch_ref[...][0]
-    hw = hw_ref[...][0]                                   # [HW_FEATURE_DIM]
-    lanes = hw[_NPE] * hw[_LANES]
-    peak_macs = lanes * hw[_FREQ]
-    stream_buf = hw[_STREAM]
+    bp = strat_ref.shape[2]
+    n = n_ref[0, 0, 0]
+    cond = lambda k: cond_ref[0, 0, k]
+    B = cond(_COND_BATCH)
+    hw = lambda k: cond(_COND_HW + k)
+    lanes = hw(_NPE) * hw(_LANES)
+    peak_macs = lanes * hw(_FREQ)
+    stream_buf = hw(_STREAM)
 
     # pack-time -> serving-datatype rescale, in-kernel (DESIGN §11/§13);
     # the multiplier is exactly 1.0 when the datatypes match
-    scale = hw[_BPE_SLOT] / bpe_ref[...][0]
-    A = A_ref[...][0] * scale                             # [P]
-    W = W_ref[...][0] * scale
-    F = F_ref[...][0]
-    OEv = OE_ref[...][0]
-    UC = UC_ref[...][0]
-    SKIP = SKIP_ref[...][0]
+    scale = hw(_BPE_SLOT) / cond(_COND_BPE)
+    row = lambda r, i: tab_ref[0, r, i]
+    A = lambda i: row(_A, i) * scale
+    W = lambda i: row(_W, i) * scale
 
     def util(mbe, oe, uc):
         return jnp.clip(mbe * oe / lanes, _UTIL_MIN, uc)
 
-    zeros = jnp.zeros((bp,), jnp.float32)
-    zmat = jnp.zeros((bp, P), jnp.float32)
-    pos = jnp.arange(P)
+    zeros = jnp.zeros((1, bp), jnp.float32)
+    gpos = jax.lax.broadcasted_iota(jnp.int32, (P, bp), 0)
+    # per-group output matrices (group id -> component sums), accumulated
+    # in place in their output blocks
+    group_refs = (Cg_ref, Tg_ref, Og_ref, Mg_ref, Wg_ref, glen_ref)
+    for ref in group_refs:
+        ref[0] = jnp.zeros((P, bp), jnp.float32)
+    gid_ref[0, 0:1, :] = jnp.zeros((1, bp), jnp.int32)   # position 0: gid 0
 
-    # per-group output matrices (group id -> component sums)
-    C_g, T_g, O_g, M_g, wave_g, glen = (zmat,) * 6
-    gid_cols = [jnp.zeros((bp,), jnp.int32)]              # position 0: gid 0
-    # open-group accumulators + strategy-prefix carry
-    g_comp = g_traf = g_on = g_mem = g_wav = g_len = zeros
-    scount = jnp.zeros((bp,), jnp.int32)                  # syncs before pos i
-    prev_sync = jnp.zeros((bp,), bool)
-    prev_mb = jnp.clip(strat[:, 0], 1.0, B)
-    lastb = jnp.full((bp,), -1.0, jnp.float32)            # last sync position
-
-    for i in range(1, P):
-        a = strat[:, i]
-        live = jnp.asarray(i <= n)                        # mask: 1 <= i <= n
-        Ai, Ap, Wi, Fi = A[i], A[i - 1], W[i], F[i]
-        OEi, UCi = OEv[i], UC[i]
-        src = SKIP[i]
-        gid_cols.append(scount)
+    def position(i, carry):
+        # open-group accumulators + strategy-prefix carry
+        # (the previous position's sync flag rides as int32: Mosaic keeps
+        # no i1 vector in a loop carry)
+        (g_comp, g_traf, g_on, g_mem, g_wav, g_len,
+         scount, prev_sync, prev_mb, lastb) = carry
+        prev_sync = prev_sync > 0
+        a = strat_ref[0, pl.ds(i, 1), :].astype(jnp.float32)
+        live = i <= n                                     # mask: 1 <= i <= n
+        Ai, Ap, Wi, Fi = A(i), A(i - 1), W(i), row(_F, i)
+        OEi, UCi = row(_OE, i), row(_UC, i)
+        src = skip_ref[0, 0, i]
+        gid_ref[0, pl.ds(i, 1), :] = scount
         sync = (a < 0.0) & live
         mb = jnp.clip(a, 1.0, B)
         mbe = jnp.where(sync, jnp.where(prev_sync, 1.0, prev_mb), mb)
@@ -117,7 +125,7 @@ def _fe_kernel(strat_ref, A_ref, W_ref, F_ref, OE_ref, UC_ref, SKIP_ref,
         # (gid[src] == gid[i]; position 0 shares gid 0 with the first group)
         has_skip = src >= 0
         same = has_skip & (src.astype(jnp.float32) > lastb)
-        Asrc = A[jnp.maximum(src, 0)]
+        Asrc = A(jnp.maximum(src, 0))
         hold = jnp.where(same, mbe * Asrc, 0.0)
         cross_t = jnp.where(has_skip & ~same, 2.0 * B * Asrc, 0.0)
 
@@ -137,7 +145,7 @@ def _fe_kernel(strat_ref, A_ref, W_ref, F_ref, OE_ref, UC_ref, SKIP_ref,
         hold_a = jnp.where(same, B * Asrc, 0.0)
         mem_a = jnp.minimum(stage * Ai + (head_f * B) * Ap + hold_a,
                             stream_buf)
-        comp_a = B * Fi / peak_macs / util(jnp.full((bp,), B), OEi, UCi)
+        comp_a = B * Fi / peak_macs / util(jnp.full((1, bp), B), OEi, UCi)
         traf_a = (head_f * B) * Ap + (tail_f * B) * Ai + Wi * 1.0 + cross_t
         on_a = B * (Ap + Ai) + Wi * 1.0
 
@@ -150,35 +158,28 @@ def _fe_kernel(strat_ref, A_ref, W_ref, F_ref, OE_ref, UC_ref, SKIP_ref,
         g_len = g_len + lv
 
         single = g_len == 1.0
-        Cc = jnp.where(single, comp_a, g_comp)
-        Tc = jnp.where(single, traf_a, g_traf)
-        Oc = jnp.where(single, on_a, g_on)
-        Mc = jnp.where(single, mem_a, g_mem)
-        Wc = jnp.where(single, 1.0, g_wav)
-
-        onehot = (pos[None, :] == scount[:, None]) & is_tail[:, None]
-        C_g = jnp.where(onehot, Cc[:, None], C_g)
-        T_g = jnp.where(onehot, Tc[:, None], T_g)
-        O_g = jnp.where(onehot, Oc[:, None], O_g)
-        M_g = jnp.where(onehot, Mc[:, None], M_g)
-        wave_g = jnp.where(onehot, Wc[:, None], wave_g)
-        glen = jnp.where(onehot, g_len[:, None], glen)
+        closed = (jnp.where(single, comp_a, g_comp),
+                  jnp.where(single, traf_a, g_traf),
+                  jnp.where(single, on_a, g_on),
+                  jnp.where(single, mem_a, g_mem),
+                  jnp.where(single, 1.0, g_wav),
+                  g_len)
+        onehot = (gpos == scount) & is_tail
+        for ref, v in zip(group_refs, closed):
+            ref[0] = jnp.where(onehot, v, ref[0])
 
         rz = lambda x: jnp.where(is_tail, zeros, x)
-        g_comp, g_traf, g_on = rz(g_comp), rz(g_traf), rz(g_on)
-        g_mem, g_wav, g_len = rz(g_mem), rz(g_wav), rz(g_len)
-        scount = scount + jnp.where(sync, 1, 0)
-        lastb = jnp.where(sync, jnp.float32(i), lastb)
-        prev_sync = sync
-        prev_mb = mb
+        sync_i = jnp.where(sync, 1, 0)
+        return (rz(g_comp), rz(g_traf), rz(g_on), rz(g_mem), rz(g_wav),
+                rz(g_len), scount + sync_i, sync_i, mb,
+                jnp.where(sync, i.astype(jnp.float32), lastb))
 
-    Cg_ref[...] = C_g[None]
-    Tg_ref[...] = T_g[None]
-    Og_ref[...] = O_g[None]
-    Mg_ref[...] = M_g[None]
-    Wg_ref[...] = wave_g[None]
-    glen_ref[...] = glen[None]
-    gid_ref[...] = jnp.stack(gid_cols, axis=-1)[None]
+    prev_mb = jnp.clip(strat_ref[0, 0:1, :].astype(jnp.float32), 1.0, B)
+    jax.lax.fori_loop(
+        1, P, position,
+        (zeros,) * 6 + (jnp.zeros((1, bp), jnp.int32),    # syncs before i
+                        jnp.zeros((1, bp), jnp.int32), prev_mb,
+                        jnp.full((1, bp), -1.0, jnp.float32)))  # last sync
 
 
 def _ceil_to(x: int, m: int) -> int:
@@ -193,30 +194,33 @@ def _fusion_eval_grid_jit(strategies, wls: dict, batches, budgets, hwrows,
     if NP != POP:
         strategies = jnp.pad(strategies, ((0, 0), (0, NP - POP), (0, 0)),
                              constant_values=_cm.SYNC)
-    row = lambda k, dt: wls[k].astype(dt).reshape(C, P)
-    args = (strategies,
-            row("A", jnp.float32), row("W", jnp.float32),
-            row("F", jnp.float32), row("OE", jnp.float32),
-            row("UC", jnp.float32), row("SKIP", jnp.int32),
-            wls["n"].astype(jnp.int32).reshape(C),
-            batches.astype(jnp.float32).reshape(C),
-            wls["BPE"].astype(jnp.float32).reshape(C),
-            hwrows.astype(jnp.float32).reshape(C, HW_FEATURE_DIM))
+    # candidates on lanes: [C, P, NP] blocks of (1, P, bp)
+    strat_t = jnp.swapaxes(strategies, 1, 2)
+    tab = jnp.stack([wls[k].astype(jnp.float32).reshape(C, P)
+                     for k in _TAB_ROWS], axis=1)
+    skip = wls["SKIP"].astype(jnp.int32).reshape(C, 1, P)
+    n = wls["n"].astype(jnp.int32).reshape(C, 1, 1)
+    cond = jnp.concatenate(
+        [batches.astype(jnp.float32).reshape(C, 1),
+         wls["BPE"].astype(jnp.float32).reshape(C, 1),
+         hwrows.astype(jnp.float32).reshape(C, HW_FEATURE_DIM)],
+        axis=1).reshape(C, 1, _COND_HW + HW_FEATURE_DIM)
 
-    cond_spec = [pl.BlockSpec((1, P), lambda c, g: (c, 0))] * 6
-    scal_spec = [pl.BlockSpec((1,), lambda c, g: (c,))] * 3
+    smem = lambda shape: pl.BlockSpec(shape, lambda c, g: (c, 0, 0),
+                                      memory_space=pltpu.SMEM)
+    blk = pl.BlockSpec((1, P, bp), lambda c, g: (c, 0, g))
     outs = pl.pallas_call(
         functools.partial(_fe_kernel, P=P),
         grid=(C, NP // bp),
-        in_specs=[pl.BlockSpec((1, bp, P), lambda c, g: (c, g, 0))]
-        + cond_spec + scal_spec
-        + [pl.BlockSpec((1, HW_FEATURE_DIM), lambda c, g: (c, 0))],
-        out_specs=[pl.BlockSpec((1, bp, P), lambda c, g: (c, g, 0))] * 7,
-        out_shape=[jax.ShapeDtypeStruct((C, NP, P), jnp.float32)] * 6
-        + [jax.ShapeDtypeStruct((C, NP, P), jnp.int32)],
+        in_specs=[smem((1, len(_TAB_ROWS), P)), smem((1, 1, P)),
+                  smem((1, 1, 1)), smem((1, 1, cond.shape[2])), blk],
+        out_specs=[blk] * 7,
+        out_shape=[jax.ShapeDtypeStruct((C, P, NP), jnp.float32)] * 6
+        + [jax.ShapeDtypeStruct((C, P, NP), jnp.int32)],
         interpret=interpret,
-    )(*args)
-    C_g, T_g, O_g, M_g, wave_g, glen, gid = (o[:, :POP] for o in outs)
+    )(tab, skip, n, cond, strat_t)
+    C_g, T_g, O_g, M_g, wave_g, glen, gid = (
+        jnp.swapaxes(o, 1, 2)[:, :POP] for o in outs)
     hw = _cm.as_hw(hwrows)
     bc = lambda x: x[:, None, None]
     hwb = jax.tree_util.tree_map(bc, hw)
@@ -225,172 +229,32 @@ def _fusion_eval_grid_jit(strategies, wls: dict, batches, budgets, hwrows,
     return out, gid, M_g
 
 
-def _block_size(pop: int, bp: int) -> int:
+_BP = 128                             # block-width cap (lanes per block)
+
+
+def _block_size(pop: int, bp: int = _BP) -> int:
     """Block width: cover small populations with one block (padded to the
-    next pow2 lane count), cap at ``bp``."""
+    next pow2 lane count), cap at ``bp``.  On the TPU a block narrower
+    than the padded population must be a multiple of 128 lanes."""
     b = 8
     while b < pop and b < bp:
         b *= 2
     return b
 
 
-# -- compiled (non-interpret) lowering: probe / fallback / autotune ----------
-#
-# ``interpret=False`` is the production path on accelerator backends: the
-# kernel lowers to Mosaic/Triton instead of being emulated op-by-op.  Not
-# every backend can lower Pallas (CPU cannot — jax raises "Only interpret
-# mode is supported on CPU backend."), so support is PROBED once per
-# process with a trivial kernel and memoized; callers that ask for the
-# compiled path on an unsupported backend get a clearly-warned interpret
-# fallback with bit-identical results (the kernel body is backend-neutral
-# jnp — the DESIGN §13 parity contract) instead of a crash.  The fallback
-# is also armed at call time: if a *specific* program fails to lower even
-# though the probe passed, that call (and all later ones) falls back too.
-
-_COMPILED_OK: bool | None = None      # memoized probe result (None = unprobed)
-_FALLBACKS = 0                        # compiled->interpret retries served
-_LEGACY_BP = 128                      # pre-autotune default block width
-
-
-def compiled_backend_supported() -> bool:
-    """Can this process's default backend lower a Pallas kernel with
-    ``interpret=False``?  Probed once with a trivial copy kernel and
-    memoized (compiling the probe is milliseconds; re-raising per call
-    would be seconds)."""
-    global _COMPILED_OK
-    if _COMPILED_OK is None:
-        def _probe(x_ref, o_ref):
-            o_ref[...] = x_ref[...] * 2.0
-        try:
-            out = pl.pallas_call(
-                _probe,
-                out_shape=jax.ShapeDtypeStruct((8, 128), jnp.float32),
-                interpret=False,
-            )(jnp.ones((8, 128), jnp.float32))
-            jax.block_until_ready(out)
-            _COMPILED_OK = True
-        except Exception:
-            _COMPILED_OK = False
-    return _COMPILED_OK
-
-
-def _note_fallback(reason: str) -> None:
-    global _COMPILED_OK, _FALLBACKS
-    _COMPILED_OK = False
-    _FALLBACKS += 1
-    if _FALLBACKS == 1:                       # warn once, count every time
-        warnings.warn(
-            f"fusion_eval: compiled (interpret=False) Pallas lowering is "
-            f"unavailable on backend '{jax.default_backend()}' — falling "
-            f"back to interpret mode (bit-identical, slower). {reason}",
-            RuntimeWarning, stacklevel=3)
-
-
-def backend_stats() -> dict:
-    """Operational visibility for the kernel lowering path: the probe
-    verdict (None until first asked), how many compiled calls fell back
-    to interpret, and the autotuned block widths chosen so far."""
-    return {
-        "backend": jax.default_backend(),
-        "compiled_supported": _COMPILED_OK,
-        "interpret_fallbacks": _FALLBACKS,
-        "autotuned_bp": dict(_AUTOTUNED),
-    }
-
-
-_AUTOTUNED: dict = {}                 # (P, pop bucket) -> chosen bp
-
-
-def autotune_block(P: int, pop: int,
-                   candidates: tuple = (32, 64, 128, 256)) -> int:
-    """Pick the fastest block width for a ``[pop, P]`` evaluation on the
-    compiled backend by timing each candidate on synthetic data (one
-    warm-up compile + best-of-2 timed calls per candidate); memoized per
-    (P, pop-bucket).  On interpret backends the block width only sets
-    emulation chunking, so the legacy default is returned untimed."""
-    key = (int(P), _block_size(pop, max(candidates)))
-    got = _AUTOTUNED.get(key)
-    if got is not None:
-        return got
-    if not compiled_backend_supported():
-        return _AUTOTUNED.setdefault(key, _block_size(pop, _LEGACY_BP))
-    import numpy as np
-    rng = np.random.default_rng(0)
-    popb = key[1]
-    strat = jnp.asarray(
-        rng.integers(-1, 5, size=(1, popb, P)).astype(np.float32))
-    wls = {"A": jnp.full((1, P), 1e4, jnp.float32),
-           "W": jnp.full((1, P), 1e4, jnp.float32),
-           "F": jnp.full((1, P), 1e6, jnp.float32),
-           "OE": jnp.ones((1, P), jnp.float32),
-           "UC": jnp.ones((1, P), jnp.float32),
-           "SKIP": jnp.full((1, P), -1, jnp.int32),
-           "n": jnp.full((1,), P - 1, jnp.int32),
-           "BPE": jnp.ones((1,), jnp.float32)}
-    batches = jnp.ones((1,), jnp.float32)
-    budgets = jnp.full((1,), 2.0 ** 24, jnp.float32)
-    hwrows = hw_array(stack_hw(None, 1))
-    best, best_t = None, float("inf")
-    for bp in candidates:
-        bpc = _block_size(popb, bp)
-        if best is not None and bpc == best:
-            continue
-        try:
-            out = _call_grid(strat, wls, batches, budgets, hwrows,
-                             bp=bpc, interpret=False)
-            jax.block_until_ready(out)
-            t = float("inf")
-            for _ in range(2):
-                t0 = time.perf_counter()
-                jax.block_until_ready(
-                    _call_grid(strat, wls, batches, budgets, hwrows,
-                               bp=bpc, interpret=False))
-                t = min(t, time.perf_counter() - t0)
-        except Exception:
-            continue
-        if t < best_t:
-            best, best_t = bpc, t
-    if best is None:                  # every candidate failed to lower
-        best = _block_size(pop, _LEGACY_BP)
-    return _AUTOTUNED.setdefault(key, best)
-
-
-def _call_grid(strategies, wls, batches, budgets, hwrows, *,
-               bp: int, interpret: bool):
-    """The one funnel to the jitted kernel: serves compiled requests on
-    unsupported backends via the warned interpret fallback, including
-    programs that fail to lower only at compile time."""
-    if not interpret and not compiled_backend_supported():
-        _note_fallback("(probe failed)")
-        interpret = True
-    try:
-        return _fusion_eval_grid_jit(strategies, wls, batches, budgets,
-                                     hwrows, bp=bp, interpret=interpret)
-    except Exception as e:
-        if interpret:
-            raise
-        _note_fallback(f"({type(e).__name__}: {e})")
-        return _fusion_eval_grid_jit(strategies, wls, batches, budgets,
-                                     hwrows, bp=bp, interpret=True)
-
-
-def _resolve_interpret(interpret: bool | None) -> bool:
-    """Default lowering: compiled wherever the backend supports it,
-    interpret otherwise (the probe, not a platform allowlist)."""
+def _call_grid(wls, strategies, batches, budgets, hw, *,
+               bp: int | None, interpret: bool | None):
+    """The one funnel to the jitted kernel.  ``interpret=None`` interprets
+    on the CPU backend and compiles everywhere else, where a lowering
+    failure raises; ``bp=None`` takes ``_block_size``'s width."""
     if interpret is None:
-        return not compiled_backend_supported()
-    return interpret
-
-
-def _resolve_bp(pop: int, P: int, bp: int | None, interpret: bool) -> int:
-    """Default block width: the autotuned choice on compiled backends,
-    the legacy default under interpret (where it only chunks emulation).
-    An explicit ``bp`` always wins (clamped to the population)."""
-    if bp is not None:
-        return _block_size(pop, bp)
-    if interpret:
-        return _block_size(pop, _LEGACY_BP)
-    return _block_size(pop, autotune_block(P, pop))
+        interpret = jax.default_backend() == "cpu"
+    strategies = jnp.asarray(strategies)
+    C, POP, _ = strategies.shape
+    return _fusion_eval_grid_jit(
+        strategies, _kernel_wls(wls), jnp.asarray(batches),
+        jnp.asarray(budgets), hw_array(stack_hw(hw, C)),
+        bp=_block_size(POP, _BP if bp is None else bp), interpret=interpret)
 
 
 def fusion_eval_grid(wls: dict, strategies, batches, budgets, hw, *,
@@ -401,16 +265,10 @@ def fusion_eval_grid(wls: dict, strategies, batches, budgets, hw, *,
     ``accel.stack_hw`` accepts).  Zero recompiles across accelerators for a
     fixed block shape — the hw row is traced kernel data.
 
-    ``interpret=None`` compiles wherever the backend can lower Pallas and
-    interprets elsewhere; ``bp=None`` autotunes the block width on
-    compiled backends (``autotune_block``)."""
-    strategies = jnp.asarray(strategies)
-    C, POP, P = strategies.shape
-    interp = _resolve_interpret(interpret)
-    out, _, _ = _call_grid(
-        strategies, _kernel_wls(wls), jnp.asarray(batches),
-        jnp.asarray(budgets), hw_array(stack_hw(hw, C)),
-        bp=_resolve_bp(POP, P, bp, interp), interpret=interp)
+    ``interpret=None`` interprets on the CPU and compiles elsewhere;
+    ``bp=None`` takes ``_block_size``'s fixed width rule."""
+    out, _, _ = _call_grid(wls, strategies, batches, budgets, hw, bp=bp,
+                           interpret=interpret)
     return out
 
 
@@ -420,13 +278,8 @@ def fusion_eval_grid_stats(wls: dict, strategies, batches, budgets, hw, *,
     """Pallas backend of ``cost_model.evaluate_grid_stats``:
     ``(CostOut [C, POP], gid [C, POP, P], M_g [C, POP, P])`` — the group
     decomposition the G-Sampler repair operator consumes."""
-    strategies = jnp.asarray(strategies)
-    C, POP, P = strategies.shape
-    interp = _resolve_interpret(interpret)
-    return _call_grid(
-        strategies, _kernel_wls(wls), jnp.asarray(batches),
-        jnp.asarray(budgets), hw_array(stack_hw(hw, C)),
-        bp=_resolve_bp(POP, P, bp, interp), interpret=interp)
+    return _call_grid(wls, strategies, batches, budgets, hw, bp=bp,
+                      interpret=interpret)
 
 
 _KERNEL_KEYS = ("A", "W", "F", "OE", "UC", "SKIP", "n", "BPE")

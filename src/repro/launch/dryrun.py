@@ -24,9 +24,9 @@ import traceback
 
 
 def _compile_variant(cfg, shape, mesh, impl, remat):
+    import jax
     from repro.launch import steps
-    from repro.launch.mesh import mesh_ctx
-    with mesh_ctx(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             jitted, args = steps.build_train_step(cfg, shape, mesh,
                                                   impl=impl, remat=remat)

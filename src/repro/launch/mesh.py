@@ -11,17 +11,7 @@ from __future__ import annotations
 
 import numpy as np
 
-__all__ = ["make_production_mesh", "mesh_ctx", "dp_axes", "batch_axes"]
-
-
-def mesh_ctx(mesh):
-    """``jax.set_mesh`` context on jax versions that have it, else the mesh
-    itself (``with mesh:`` — the pre-0.5 spelling of the same thing).  The
-    single home of this version shim; dryrun and the sharding tests both
-    use it."""
-    import jax
-    set_mesh = getattr(jax, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
+__all__ = ["make_production_mesh", "dp_axes", "batch_axes"]
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -33,12 +23,10 @@ def make_production_mesh(*, multi_pod: bool = False):
         raise RuntimeError(
             f"need {n} devices for mesh {shape}, have {len(jax.devices())} "
             f"(dry-run must set xla_force_host_platform_device_count first)")
-    try:
-        return jax.make_mesh(shape, axes)
-    except ValueError:
-        from jax.sharding import Mesh
-        devs = np.asarray(jax.devices()[:n]).reshape(shape)
-        return Mesh(devs, axes)
+    # Auto axes: the model's ``logical_shard`` calls are layout hints for
+    # the partitioner, which Explicit axes would turn into assertions
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def dp_axes(mesh) -> tuple[str, ...]:

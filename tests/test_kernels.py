@@ -264,57 +264,74 @@ def test_model_pallas_path_matches_xla():
                                rtol=2e-4, atol=2e-4)
 
 
-# --- compiled (non-interpret) lowering: probe / fallback / autotune ---------
-
-def test_compiled_backend_probe_memoized_on_cpu():
-    """The CPU container cannot lower Pallas compiled; the probe must say
-    so (memoized — the second call is free)."""
-    assert fusion_eval.compiled_backend_supported() is False
-    assert fusion_eval.compiled_backend_supported() is False
-    s = fusion_eval.backend_stats()
-    assert s["backend"] == "cpu" and s["compiled_supported"] is False
+# --- lowering mode and block width: chosen from the platform, no fallback ---
 
 
-def test_compiled_request_falls_back_bit_identically():
-    """Explicitly asking for interpret=False on an unsupported backend
-    must WARN and serve the interpret result — bit-identical, no crash
-    (the DESIGN §14 graceful-fallback contract)."""
-    import warnings
+def _spy_grid_jit(monkeypatch):
+    """Record the static (bp, interpret) each call hands the jitted kernel."""
+    seen = []
+    real = fusion_eval._fusion_eval_grid_jit
+
+    def spy(*args, bp, interpret):
+        seen.append((bp, interpret))
+        return real(*args, bp=bp, interpret=interpret)
+    monkeypatch.setattr(fusion_eval, "_fusion_eval_grid_jit", spy)
+    return seen
+
+
+def test_cpu_platform_resolves_to_interpret(monkeypatch):
+    """interpret=None on the CPU backend interprets, and gives the same
+    bits as an explicit interpret=True."""
+    assert jax.default_backend() == "cpu"
+    seen = _spy_grid_jit(monkeypatch)
+    auto = ops.fusion_eval_population(_FE_POP, _FE_PACKED, batch=32.0,
+                                      budget_bytes=20 * MB, hw=PAPER_ACCEL)
     want = ops.fusion_eval_population(_FE_POP, _FE_PACKED, batch=32.0,
                                       budget_bytes=20 * MB, hw=PAPER_ACCEL,
                                       interpret=True)
-    before = fusion_eval.backend_stats()["interpret_fallbacks"]
-    with warnings.catch_warnings(record=True) as rec:
-        warnings.simplefilter("always")
-        got = ops.fusion_eval_population(_FE_POP, _FE_PACKED, batch=32.0,
-                                         budget_bytes=20 * MB,
-                                         hw=PAPER_ACCEL, interpret=False)
-    _assert_costout_equal(got, want)
-    stats = fusion_eval.backend_stats()
-    assert stats["interpret_fallbacks"] == before + 1
-    if before == 0:                              # warn once, count always
-        assert any("interpret mode" in str(w.message) for w in rec)
-    # the default (interpret=None) resolves to the probe verdict, so the
-    # same call without flags is also bit-identical
-    auto = ops.fusion_eval_population(_FE_POP, _FE_PACKED, batch=32.0,
-                                      budget_bytes=20 * MB, hw=PAPER_ACCEL)
+    assert [i for _, i in seen] == [True, True]
     _assert_costout_equal(auto, want)
 
 
-def test_autotune_block_on_interpret_backend_returns_legacy_default():
-    """Autotuning times compiled programs; under interpret it must return
-    the legacy block width untimed (and memoize it), so bp=None keeps
-    CPU-CI behavior identical to the old bp=128 default."""
-    bp = fusion_eval.autotune_block(64, _FE_POP.shape[0])
-    assert bp == fusion_eval._block_size(_FE_POP.shape[0], 128)
-    key = (64, fusion_eval._block_size(_FE_POP.shape[0], 256))
-    assert fusion_eval.backend_stats()["autotuned_bp"][key] == bp
+def test_compiled_request_on_cpu_raises():
+    """An explicit interpret=False on the CPU raises Pallas's own error;
+    nothing falls back to interpret mode."""
+    with pytest.raises(ValueError, match="interpret mode"):
+        ops.fusion_eval_population(_FE_POP, _FE_PACKED, batch=32.0,
+                                   budget_bytes=20 * MB, hw=PAPER_ACCEL,
+                                   interpret=False)
+
+
+@pytest.mark.parametrize("pop_n,width", [(5, 8), (64, 64), (200, 128)])
+def test_default_block_width_is_fixed_rule(monkeypatch, pop_n, width):
+    """bp=None takes _block_size's width: one pow2 block up to 128 lanes,
+    128-lane blocks beyond (the TPU tiling rule for a partial block)."""
+    seen = _spy_grid_jit(monkeypatch)
+    ops.fusion_eval_population(_FE_POP[np.arange(pop_n) % len(_FE_POP)],
+                               _FE_PACKED, batch=32.0, budget_bytes=20 * MB,
+                               hw=PAPER_ACCEL)
+    assert seen == [(fusion_eval._block_size(pop_n), True)]
+    assert fusion_eval._block_size(pop_n) == width
+
+
+def test_gsampler_pallas_evaluator_inside_jit_runs_on_cpu():
+    """G-Sampler asks for the Pallas evaluator from inside its jitted
+    body; the lowering mode must be decided there from the platform, with
+    no probe or tuner running on tracers."""
+    from repro.core import GSamplerConfig, gsampler_search_grid
+    cfg = GSamplerConfig(population=8, generations=2, elite=2,
+                         repair_tries=1, seed=0)
+    res = gsampler_search_grid([tiny_cnn()], [PAPER_ACCEL], [8.0],
+                               [4 * MB], nmax=8, cfg=cfg, top_k=2,
+                               evaluator="pallas")
+    assert np.isfinite(np.asarray(res.latency)).all()
+    assert np.asarray(res.valid).any()
 
 
 # ---------------------------------------------------------------------------
 # optimality oracle (DESIGN §16): every production evaluator — XLA
-# evaluate_population, the Pallas kernel (interpret AND the compiled->
-# interpret fallback entry), and the prefix-scan serving evaluator — is
+# evaluate_population, the Pallas kernel (explicit interpret and the
+# platform-resolved default), and the prefix-scan serving evaluator — is
 # pinned against the exact f64 brute-force optimum on the shared
 # adversarial workload set.
 # ---------------------------------------------------------------------------
@@ -351,7 +368,7 @@ def test_evaluators_agree_with_f64_oracle_adversarial(case):
                                              hw=serve_hw, interpret=True),
         "pallas_auto": ops.fusion_eval_population(
             pop, wl_np, batch=float(batch), budget_bytes=float(budget),
-            hw=serve_hw),                    # compiled-or-fallback resolve
+            hw=serve_hw),                    # platform-resolved default
     }
     scans = [cm.prefix_scan(wl_np, jnp.asarray(s), float(batch),
                             float(budget), serve_hw)[1] for s in pop]

@@ -67,11 +67,11 @@ def test_mini_dryrun_16_devices(tmp_path):
         from repro.configs import get_config, Shape
         from repro.launch import steps
         from repro.launch.hlo_analysis import collective_bytes
-        from repro.launch.mesh import mesh_ctx
-        mesh = jax.make_mesh((4, 4), ("data", "model"))
+        mesh = jax.make_mesh((4, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         cfg = get_config("qwen3_8b", reduced=True)
         shape = Shape("t", 128, 8, "train")
-        with mesh_ctx(mesh):
+        with jax.set_mesh(mesh):
             jitted, args = steps.build_train_step(cfg, shape, mesh)
             compiled = jitted.lower(*args).compile()
         mem = compiled.memory_analysis()
