@@ -1,0 +1,5 @@
+"""Share of the traced stretch in which no operation ran on the device."""
+
+
+def read(rec):
+    return 100.0 * rec.trace["idle_share"]
