@@ -58,6 +58,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench.harness import Compiles, require_chips
+
 ROOT = pathlib.Path(__file__).resolve().parent
 MB = float(2 ** 20)
 
@@ -94,31 +96,16 @@ def log(msg: str) -> None:
 
 class Smoke:
     """One smoke run's record: backend compile events and persistent-cache
-    hits (from JAX's monitoring events; a program read from the cache is
-    both an event and a hit), per-phase timing, and the checks that
-    failed.  A failed
-    check is printed and recorded, the remaining phases still run, and
-    ``main`` exits non-zero at the end."""
+    hits (the benchmark's ``Compiles``), per-phase timing, and the checks
+    that failed.  A failed check is printed and recorded, the remaining
+    phases still run, and ``main`` exits non-zero at the end."""
 
     def __init__(self):
-        self.compiles = 0
-        self.compile_s = 0.0
-        self.cache_hits = 0
+        self.compiles = Compiles()
         self.failed: list[str] = []
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event: str, secs: float, **_) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.compiles += 1
-            self.compile_s += secs
-
-    def _event(self, event: str, **_) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
 
     def snapshot(self) -> tuple:
-        return self.compiles, self.compile_s, self.cache_hits
+        return self.compiles.snapshot()
 
     def check(self, cond: bool, msg: str) -> None:
         if not cond:
@@ -134,19 +121,6 @@ class Smoke:
         log(f"[phase] {name}: {time.perf_counter() - t0:.2f} s; backend "
             f"compile events {c1[0] - c0[0]} ({c1[1] - c0[1]:.2f} s), "
             f"of them persistent-cache hits {c1[2] - c0[2]}")
-
-
-def require_tpu(n_chips: int):
-    devs = jax.devices()
-    if devs[0].platform != "tpu":
-        sys.exit(f"chip_smoke: no TPU found (JAX's first device is "
-                 f"{devs[0].platform!r}); this smoke runs only on a TPU")
-    if len(devs) < n_chips:
-        sys.exit(f"chip_smoke: --chips {n_chips} needs {n_chips} TPU "
-                 f"devices, found {len(devs)}")
-    log(f"device: platform={devs[0].platform} kind={devs[0].device_kind} "
-        f"count={len(devs)}")
-    return devs
 
 
 def serve_networks():
@@ -447,7 +421,9 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
-    devs = require_tpu(args.chips)
+    devs = require_chips(args.chips)
+    log(f"device: platform={devs[0].platform} kind={devs[0].device_kind} "
+        f"count={len(devs)}")
     sys.path.insert(0, str(ROOT / "src"))
     from repro.compile_cache import use_compile_cache
     log(f"compile cache: {use_compile_cache()}")

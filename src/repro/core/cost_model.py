@@ -376,12 +376,14 @@ def evaluate_grid(wls: dict, strategies: jax.Array, batches: jax.Array,
 
     ``evaluator`` selects the backend (DESIGN §13): "xla" vmaps the jnp
     evaluator, "pallas" runs the ``kernels.fusion_eval`` block kernel
-    (interpret mode on CPU) — bit-identical outputs either way."""
-    if _resolve_evaluator(evaluator) == "pallas":
-        from ..kernels.fusion_eval import fusion_eval_grid
-        return fusion_eval_grid(wls, strategies, batches, budgets, hw)
-    return _grid_jit(wls, strategies, batches, budgets,
-                     stack_hw(hw, strategies.shape[0]))
+    (interpret mode on CPU) — bit-identical outputs either way.  Either
+    runs under the name scope ``evaluate_grid``."""
+    with jax.named_scope("evaluate_grid"):
+        if _resolve_evaluator(evaluator) == "pallas":
+            from ..kernels.fusion_eval import fusion_eval_grid
+            return fusion_eval_grid(wls, strategies, batches, budgets, hw)
+        return _grid_jit(wls, strategies, batches, budgets,
+                         stack_hw(hw, strategies.shape[0]))
 
 
 @jax.jit
@@ -398,13 +400,15 @@ def evaluate_grid_stats(wls: dict, strategies: jax.Array, batches: jax.Array,
     """Grid counterpart of :func:`evaluate_population_stats`:
     ``(CostOut [C, POP], gid [C, POP, P], M_g [C, POP, P])`` — the
     constraint-repair operator's split/shrink targets for every child of
-    every condition in one call.  ``evaluator`` as in
+    every condition in one call.  ``evaluator`` and the name scope as in
     :func:`evaluate_grid` (DESIGN §13)."""
-    if _resolve_evaluator(evaluator) == "pallas":
-        from ..kernels.fusion_eval import fusion_eval_grid_stats
-        return fusion_eval_grid_stats(wls, strategies, batches, budgets, hw)
-    return _grid_stats_jit(wls, strategies, batches, budgets,
-                           stack_hw(hw, strategies.shape[0]))
+    with jax.named_scope("evaluate_grid"):
+        if _resolve_evaluator(evaluator) == "pallas":
+            from ..kernels.fusion_eval import fusion_eval_grid_stats
+            return fusion_eval_grid_stats(wls, strategies, batches, budgets,
+                                          hw)
+        return _grid_stats_jit(wls, strategies, batches, budgets,
+                               stack_hw(hw, strategies.shape[0]))
 
 
 @jax.jit

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs import span
 from . import cost_model as cm
 from .accel import AccelConfig, HwVec, stack_hw
 
@@ -226,8 +227,15 @@ class GridTeacherResult:
     valid: np.ndarray        # [C, K] bool
     history: np.ndarray      # [G, C] best valid speedup per generation
     baseline_latency: np.ndarray   # [C]
-    n_evals: int
+    repair_rounds: np.ndarray      # [G] repair rounds run per generation
+    n_evals: int             # exact cost evaluations, over all conditions
     wall_s: float
+
+
+# host seconds and count of ``gsampler_search_grid``'s spans (``obs.span``)
+spans: dict = {}
+# binary-search steps of the naive uniform seed (``_naive_uniform_grid``)
+SEED_ITERS = 18
 
 
 def _randint_1_to_B(key, shape, B) -> jax.Array:
@@ -241,7 +249,7 @@ def _fitness_jnp(latency, peak, budget):
     return jnp.where(over > 0.0, -1e3 * (1.0 + over) - latency, -latency)
 
 
-def _naive_uniform_grid(wls, batches, budgets, hw, iters: int = 18,
+def _naive_uniform_grid(wls, batches, budgets, hw, iters: int = SEED_ITERS,
                         evaluator: str = "xla"):
     """Device twin of :func:`naive_uniform_mb`: per-condition binary search
     for the largest uniform micro-batch that stages everything on-chip."""
@@ -306,7 +314,9 @@ def _repair_grid(key, wls, brood, batches, budgets, hw, cfg: GSamplerConfig,
     """Constraint repair for every condition's brood at once: while a child
     is over budget, split its worst fused group or shrink that group's
     largest staged micro-batch — the same operator as
-    :func:`_repair_population`, with the span/argmax logic in jnp."""
+    :func:`_repair_population`, with the span/argmax logic in jnp.
+    Returns the repaired brood and the number of rounds run (one
+    ``evaluate_grid_stats`` call each), under the name scope ``repair``."""
     C, K, P = brood.shape
     pos = jnp.arange(P)
     mask = wls["mask"]                                            # [C, P]
@@ -345,16 +355,18 @@ def _repair_grid(key, wls, brood, batches, budgets, hw, cfg: GSamplerConfig,
         s = jnp.where(apply[..., None], new, s)
         return s, key, i + 1, invalid.any()
 
-    s, _, _, _ = jax.lax.while_loop(
-        cond_fn, round_fn, (brood, key, jnp.int32(0), jnp.bool_(True)))
-    return s
+    with jax.named_scope("repair"):
+        s, _, rounds, _ = jax.lax.while_loop(
+            cond_fn, round_fn, (brood, key, jnp.int32(0), jnp.bool_(True)))
+    return s, rounds
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "top_k", "evaluator"))
 def _ga_grid(key, wls, batches, budgets, hw,
              cfg: GSamplerConfig, top_k: int, evaluator: str = "xla"):
     """The whole grid GA as one device program.  Returns stacked elites
-    [C, top_k, P] with exact costs, plus the best-valid-speedup history.
+    [C, top_k, P] with exact costs, plus the best-valid-speedup history
+    and the repair rounds of each generation.
 
     ``evaluator`` selects the fitness/repair backend (DESIGN §13); the
     backends are bit-identical, so the evolved populations — and therefore
@@ -397,16 +409,16 @@ def _ga_grid(key, wls, batches, budgets, hw,
                             * n[:, None]).astype(jnp.int32)
         child = jnp.where(pos[None, None, :] < cut[..., None], pa, pb)
         child = _mutate_grid(km, child, valid_pos, n, B, cfg)
-        brood = _repair_grid(kr, wls, child, batches, budgets, hw, cfg,
-                             evaluator=evaluator)
+        brood, rounds = _repair_grid(kr, wls, child, batches, budgets, hw,
+                                     cfg, evaluator=evaluator)
         new_pop = jnp.concatenate([elites, brood], axis=1)
         sp = base[:, None] / jnp.maximum(out.latency, 1e-12)
         best = jnp.max(jnp.where(out.valid, sp, 0.0), axis=1)
-        return new_pop, best
+        return new_pop, (best, rounds)
 
     key, k_scan = jax.random.split(key)
-    pop, history = jax.lax.scan(gen, pop,
-                                jax.random.split(k_scan, cfg.generations))
+    pop, (history, repair_rounds) = jax.lax.scan(
+        gen, pop, jax.random.split(k_scan, cfg.generations))
 
     out = cm.evaluate_grid(wls, pop, batches, budgets, hw,
                            evaluator=evaluator)
@@ -418,7 +430,8 @@ def _ga_grid(key, wls, batches, budgets, hw,
     return dict(strategies=strategies, latency=lat, peak_mem=peak,
                 valid=take(out.valid) & (take(fit) > -1e3),
                 speedup=base[:, None] / jnp.maximum(lat, 1e-12),
-                history=history, baseline_latency=base)
+                history=history, baseline_latency=base,
+                repair_rounds=repair_rounds)
 
 
 def gsampler_search_grid(workloads: list, hw, batches,
@@ -446,34 +459,47 @@ def gsampler_search_grid(workloads: list, hw, batches,
     assert len(workloads) == len(batches) == len(budgets_bytes)
     t0 = time.perf_counter()
     C = len(workloads)
-    if isinstance(hw, AccelConfig) or (
-            isinstance(hw, (list, tuple)) and not isinstance(hw, HwVec)):
-        hws = list(hw) if isinstance(hw, (list, tuple)) else [hw] * C
-        assert len(hws) == C
-        if packed is None:
-            packed = cm.stack_workloads(
-                [cm.pack_workload(w, h, nmax) for w, h in zip(workloads,
-                                                              hws)])
-        hwv = stack_hw(hws, C)
-    else:
-        # already-vectorized hardware (stacked HwVec / raw [C, F] array):
-        # packing needs host AccelConfigs, so the caller must supply it
-        if packed is None:
-            raise ValueError("vectorized hw (HwVec / raw array) requires "
-                             "`packed=` — pack_workload needs AccelConfigs")
-        hwv = stack_hw(hw, C)
-    wls = packed
-    batches = jnp.asarray(np.asarray(batches, np.float32))
-    budgets = jnp.asarray(np.asarray(budgets_bytes, np.float32))
-    out = _ga_grid(jax.random.PRNGKey(cfg.seed), wls, batches, budgets,
-                   hwv, cfg, top_k, cm._resolve_evaluator(evaluator))
-    out = {k: np.asarray(v) for k, v in out.items()}
-    # upper bound: the repair while_loop exits early once a brood is valid
-    n_evals = C * cfg.population * (cfg.generations
-                                    * (1 + cfg.repair_tries) + 1)
+    with span("gsampler.pack", spans):
+        if isinstance(hw, AccelConfig) or (
+                isinstance(hw, (list, tuple)) and not isinstance(hw, HwVec)):
+            hws = list(hw) if isinstance(hw, (list, tuple)) else [hw] * C
+            assert len(hws) == C
+            if packed is None:
+                packed = cm.stack_workloads(
+                    [cm.pack_workload(w, h, nmax)
+                     for w, h in zip(workloads, hws)])
+            hwv = stack_hw(hws, C)
+        else:
+            # already-vectorized hardware (stacked HwVec / raw [C, F]
+            # array): packing needs host AccelConfigs, so the caller must
+            # supply it
+            if packed is None:
+                raise ValueError("vectorized hw (HwVec / raw array) requires "
+                                 "`packed=` — pack_workload needs "
+                                 "AccelConfigs")
+            hwv = stack_hw(hw, C)
+        wls = packed
+        batches = jnp.asarray(np.asarray(batches, np.float32))
+        budgets = jnp.asarray(np.asarray(budgets_bytes, np.float32))
+        key = jax.random.PRNGKey(cfg.seed)
+    with span("gsampler.dispatch", spans):
+        out = _ga_grid(key, wls, batches, budgets, hwv, cfg, top_k,
+                       cm._resolve_evaluator(evaluator))
+    with span("gsampler.wait", spans):
+        out = jax.block_until_ready(out)
+    with span("gsampler.unpack", spans) as sp:
+        out = {k: np.asarray(v) for k, v in out.items()}
+        rounds = int(out["repair_rounds"].sum())
+        sp.set_metadata(repair_rounds=rounds, generations=cfg.generations)
+    # every evaluation, per condition: the seed's binary search, each
+    # generation's population and its repair rounds over the brood, and
+    # the final population
+    n_evals = C * (SEED_ITERS + cfg.population * (cfg.generations + 1)
+                   + rounds * (cfg.population - cfg.elite))
     return GridTeacherResult(
         strategies=out["strategies"], latency=out["latency"],
         peak_mem=out["peak_mem"], speedup=out["speedup"],
         valid=out["valid"], history=out["history"],
-        baseline_latency=out["baseline_latency"], n_evals=n_evals,
+        baseline_latency=out["baseline_latency"],
+        repair_rounds=out["repair_rounds"], n_evals=n_evals,
         wall_s=time.perf_counter() - t0)

@@ -56,6 +56,7 @@ class InferResult:
     valid: bool
     wall_s: float
     n_model_calls: int
+    guard_iters: int         # the budget guard's halvings and syncs
 
 
 @partial(jax.jit, static_argnames=("cfg", "backend"))
@@ -82,7 +83,7 @@ def _rollout(backend, params, cfg, env: FusionEnv, *,
     hwf = _hw_condition(cfg, env)
     t0 = time.perf_counter()
     s = env.reset()
-    calls = 0
+    calls = guard_iters = 0
     for t in range(env.n + 1):
         states[0, t] = s
         rtg[0, t] = env.reward_to_go
@@ -106,6 +107,7 @@ def _rollout(backend, params, cfg, env: FusionEnv, *,
                 if float(out.peak_mem) <= env.budget_bytes:
                     break
                 a = a // 2 if a > 1 else cm.SYNC
+                guard_iters += 1
         actions[0, t] = encode_action(np.float32(a), env.batch)
         s, _, done = env.step(a)
     wall = time.perf_counter() - t0
@@ -113,7 +115,7 @@ def _rollout(backend, params, cfg, env: FusionEnv, *,
     out = env.evaluate_strategy(strat)
     return InferResult(strat, env.baseline_latency / float(out.latency),
                        float(out.latency), float(out.peak_mem),
-                       bool(out.valid), wall, calls)
+                       bool(out.valid), wall, calls, guard_iters)
 
 
 def dnnfuser_infer(params, cfg, env: FusionEnv, *,
@@ -150,46 +152,58 @@ def _fused_episode(params, cfg, wl, batch, budget_bytes, hw,
     def guard(carry, a):
         """The host probe loop: shrink / sync until the staged prefix plus
         an all-SYNC suffix fits the budget (paper's inference-time
-        constraint guard).  Probes via the peak-only fast path."""
-        def cond(av):
-            return (av >= 1) & (cm.prefix_probe_peak(consts.pc, carry, av,
-                                                     hw) > budget)
-        def body(av):
-            return jnp.where(av > 1, av // 2, jnp.int32(cm.SYNC))
-        return jax.lax.while_loop(cond, body, a)
+        constraint guard).  Probes via the peak-only fast path.  Returns
+        the action and how many times it was halved or synced."""
+        def cond(c):
+            return (c[0] >= 1) & (cm.prefix_probe_peak(consts.pc, carry, c[0],
+                                                       hw) > budget)
+        def body(c):
+            av, k = c
+            return jnp.where(av > 1, av // 2, jnp.int32(cm.SYNC)), k + 1
+        return jax.lax.while_loop(cond, body, (a, jnp.int32(0)))
 
     # --- t = 0: prefill (r_0, s_0); the input micro-batch cannot sync ------
-    carry0 = env_reset(consts)
-    r0, s0 = env_observe(consts, carry0, hw)
-    pred0, mstate = backend.prefill(params, cfg, backend.state_init(cfg),
-                                    r0[None], s0[None], hwb)
-    a0 = jnp.maximum(decode_action_jnp(pred0[0], B), 1)
-    carry = env_step(consts, carry0, a0, hw)
-    actions = jnp.full((P,), cm.SYNC, jnp.int32).at[0].set(a0)
+    with jax.named_scope("env_step"):
+        carry0 = env_reset(consts)
+        r0, s0 = env_observe(consts, carry0, hw)
+    with jax.named_scope("dt_decode"):
+        pred0, mstate = backend.prefill(params, cfg, backend.state_init(cfg),
+                                        r0[None], s0[None], hwb)
+        a0 = jnp.maximum(decode_action_jnp(pred0[0], B), 1)
+    with jax.named_scope("env_step"):
+        carry = env_step(consts, carry0, a0, hw)
+        actions = jnp.full((P,), cm.SYNC, jnp.int32).at[0].set(a0)
 
     def step(sc, t):
-        carry, mstate, a_prev, actions = sc
+        carry, mstate, a_prev, actions, iters = sc
         active = t <= n
-        r_t, s_t = env_observe(consts, carry, hw)
-        pred, mstate = backend.step(params, cfg, mstate, r_t[None], s_t[None],
-                                    encode_action_jnp(a_prev, B)[None], hwb)
-        a = decode_action_jnp(pred[0], B)
+        with jax.named_scope("env_step"):
+            r_t, s_t = env_observe(consts, carry, hw)
+        with jax.named_scope("dt_decode"):
+            pred, mstate = backend.step(params, cfg, mstate, r_t[None],
+                                        s_t[None],
+                                        encode_action_jnp(a_prev, B)[None],
+                                        hwb)
+            a = decode_action_jnp(pred[0], B)
         if repair:
-            a = guard(carry, a)
-        a = jnp.where(active, a, jnp.int32(cm.SYNC))
-        new_carry = env_step(consts, carry, a, hw)
-        carry = cm._tree_select(active, new_carry, carry)
-        actions = actions.at[t].set(a)
-        a_prev = jnp.where(active, a, a_prev)
-        return (carry, mstate, a_prev, actions), None
+            with jax.named_scope("guard"):
+                a, k = guard(carry, a)
+                iters = iters + jnp.where(active, k, 0)
+        with jax.named_scope("env_step"):
+            a = jnp.where(active, a, jnp.int32(cm.SYNC))
+            new_carry = env_step(consts, carry, a, hw)
+            carry = cm._tree_select(active, new_carry, carry)
+            actions = actions.at[t].set(a)
+            a_prev = jnp.where(active, a, a_prev)
+        return (carry, mstate, a_prev, actions, iters), None
 
-    (carry, _, _, actions), _ = jax.lax.scan(
-        step, (carry, mstate, a0, actions), jnp.arange(1, P))
+    (carry, _, _, actions, iters), _ = jax.lax.scan(
+        step, (carry, mstate, a0, actions, jnp.int32(0)), jnp.arange(1, P))
     out = env_final(consts, carry, hw)
     return dict(strategy=actions, latency=out.latency,
                 peak_mem=out.peak_mem, valid=out.valid,
                 speedup=consts.base_lat / jnp.maximum(out.latency, 1e-12),
-                baseline_latency=consts.base_lat)
+                baseline_latency=consts.base_lat, guard_iters=iters)
 
 
 @partial(jax.jit, static_argnames=("cfg", "repair", "backend"))
@@ -223,7 +237,7 @@ def _fused_infer(backend, params, cfg, env: FusionEnv, repair) -> InferResult:
     wall = time.perf_counter() - t0
     return InferResult(strat, float(out["speedup"]), float(out["latency"]),
                        float(out["peak_mem"]), bool(out["valid"]), wall,
-                       env.n + 1)
+                       env.n + 1, int(out["guard_iters"]))
 
 
 def dnnfuser_infer_fused(params, cfg, env: FusionEnv, *,
@@ -261,7 +275,8 @@ def dnnfuser_infer_batch(params, cfg, env_or_wl, batches,
     heterogeneous per-row accelerators serve in the same fused call
     (DESIGN §11).  Any registered ``MapperBackend`` config works (DT and
     seq2seq).  Returns a dict of stacked arrays (strategy [C, P] int32,
-    latency/peak_mem/speedup/valid [C])."""
+    latency/peak_mem/speedup/valid [C], and guard_iters [C]: the budget
+    guard's halvings and syncs over each row's true steps)."""
     if isinstance(env_or_wl, FusionEnv):
         wl = env_or_wl.wl
         if hw is None:

@@ -35,6 +35,7 @@ from __future__ import annotations
 import time
 from collections import OrderedDict
 
+from ..obs import span
 from .engine import MapperEngine, MapRequest, MapResponse
 from .bucketing import nmax_bucket
 from .config import ServingConfig, _SCHEDULER_FIELDS, config_from_kwargs
@@ -125,6 +126,9 @@ class AsyncMapperScheduler:
         self.rejected = 0
         self.resolved_at_submit = 0
         self.flushes = {"width": 0, "deadline": 0, "force": 0}
+        self.queue_wait_s = 0.0                    # flush start - submit
+        self.queued = 0                            # requests flushed
+        self.spans: dict = {}                      # obs.span tallies
         engine.scheduler = self                    # stats() backref
 
     # -- intake --------------------------------------------------------------
@@ -175,6 +179,10 @@ class AsyncMapperScheduler:
         p50/p99 from :attr:`MapFuture.latency_s` include both queueing
         delay and real compute.  With ``now=None`` the real clock
         drives everything."""
+        with span("scheduler.pump", self.spans):
+            return self._pump(now, force)
+
+    def _pump(self, now: float | None, force: bool) -> int:
         simulated = now is not None
         now = self.clock() if now is None else now
         resolved = 0
@@ -194,16 +202,24 @@ class AsyncMapperScheduler:
             self._lanes[nb] = []
             self.queue_depth -= len(lane)
             self.flushes[reason] += 1
-            wall0 = time.perf_counter()
-            responses = self.engine.serve([f.request for f in lane])
-            elapsed = time.perf_counter() - wall0
-            if simulated:
-                t_done = max(now, self._server_free) + elapsed
-                self._server_free = t_done
-            else:
-                t_done = self.clock()
-            for fut, resp in zip(lane, responses):
-                fut._resolve(resp, t_done)
+            with span("scheduler.flush", self.spans) as sp:
+                t_flush = (max(now, self._server_free) if simulated
+                           else self.clock())
+                wait = sum(t_flush - f.t_submit for f in lane)
+                self.queue_wait_s += wait
+                self.queued += len(lane)
+                sp.set_metadata(nmax=nb, requests=len(lane), reason=reason,
+                                wait_s=wait)
+                wall0 = time.perf_counter()
+                responses = self.engine.serve([f.request for f in lane])
+                elapsed = time.perf_counter() - wall0
+                if simulated:
+                    t_done = max(now, self._server_free) + elapsed
+                    self._server_free = t_done
+                else:
+                    t_done = self.clock()
+                for fut, resp in zip(lane, responses):
+                    fut._resolve(resp, t_done)
             resolved += len(lane)
         return resolved
 
@@ -240,4 +256,7 @@ class AsyncMapperScheduler:
             "rejected": self.rejected,
             "resolved_at_submit": self.resolved_at_submit,
             "flushes": dict(self.flushes),
+            "queue_wait_s": self.queue_wait_s,
+            "queued": self.queued,
+            "spans": {k: dict(v) for k, v in self.spans.items()},
         }

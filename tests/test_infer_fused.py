@@ -151,6 +151,32 @@ def test_fused_guard_repairs_over_budget_strategies():
         assert (h.strategy == f.strategy).all()
 
 
+def test_guard_iters_count_the_host_guards_halvings_and_syncs():
+    """``guard_iters`` counts, lane by lane, the halvings and syncs the
+    host guard makes over the lane's true steps; the other outputs keep
+    matching the host rollout."""
+    wl = vgg16()
+    params = _biased(dt_init(jax.random.PRNGKey(0), CFG), 0.9)
+    batches = np.array([64.0, 32.0, 16.0, 64.0], np.float32)
+    budgets = np.array([4.0, 6.0, 10.0, 64.0], np.float32) * MB
+    env0 = FusionEnv(wl, HW, batch=64, budget_bytes=32 * MB,
+                     nmax=CFG.max_steps)
+    out = dnnfuser_infer_batch(params, CFG, env0, batches, budgets)
+    off = dnnfuser_infer_batch(params, CFG, env0, batches, budgets,
+                               repair=False)
+    assert (off["guard_iters"] == 0).all()
+    for i in range(len(batches)):
+        env = FusionEnv(wl, HW, batch=int(batches[i]),
+                        budget_bytes=float(budgets[i]), nmax=CFG.max_steps)
+        h = dnnfuser_infer(params, CFG, env, repair=True)
+        assert int(out["guard_iters"][i]) == h.guard_iters, i
+        assert (out["strategy"][i] == h.strategy).all(), i
+        np.testing.assert_allclose(out["latency"][i], h.latency, rtol=1e-5)
+        f = dnnfuser_infer_fused(params, CFG, env, repair=True)
+        assert f.guard_iters == h.guard_iters
+    assert out["guard_iters"][:3].min() > 0     # the guard did work
+
+
 def test_infer_batch_matches_single_condition_runs():
     wl = resnet18()
     params = dt_init(jax.random.PRNGKey(2), CFG)

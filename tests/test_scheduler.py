@@ -120,6 +120,28 @@ def test_scheduler_width_and_deadline_flushes():
     _assert_same_response(d.result(), a.result())
 
 
+def test_scheduler_queue_wait_and_spans():
+    """``queue_wait_s`` sums flush start minus submit over the flushed
+    requests, on the scheduler's clock; every pump and every flushed lane
+    is a span."""
+    eng = MapperEngine(PARAMS, CFG)
+    sched = AsyncMapperScheduler(eng, flush_ms=10.0, max_wave=8)
+    sched.submit(MapRequest(tiny_cnn(), 16, 8 * MB, ACCEL_ZOO["edge"]),
+                 now=0.0)
+    sched.submit(MapRequest(tiny_cnn(), 32, 9 * MB, ACCEL_ZOO["edge"]),
+                 now=0.004)
+    sched.pump(now=0.005)                        # nothing due yet
+    sched.pump(now=0.020)                        # one lane, on deadline
+    st = sched.stats()
+    assert st["queued"] == 2 and st["flushes"]["deadline"] == 1
+    assert st["queue_wait_s"] == pytest.approx(0.020 + 0.016)
+    assert st["spans"]["scheduler.pump"]["count"] == 2
+    assert st["spans"]["scheduler.flush"]["count"] == 1
+    # the flushes' engine time lies inside the pumps
+    assert (eng.stats()["spans"]["engine.serve"]["seconds"]
+            <= st["spans"]["scheduler.pump"]["seconds"])
+
+
 def test_scheduler_admission_control():
     eng = MapperEngine(PARAMS, CFG)
     sched = AsyncMapperScheduler(eng, max_queue=2, flush_ms=1e3, max_wave=8)

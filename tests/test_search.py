@@ -95,6 +95,32 @@ def test_gsampler_grid_backend_equivalence():
     assert res["xla"].valid.any()           # the grid actually solved
 
 
+@pytest.mark.parametrize("budget_mb,rounds", [(1e-3, _BE_CFG.repair_tries),
+                                               (1e6, 1)])
+def test_repair_rounds_and_exact_n_evals(budget_mb, rounds):
+    """Under a budget nothing fits, every generation runs all
+    ``repair_tries`` rounds; under one everything fits, the first round
+    finds the brood valid and stops.  ``n_evals`` counts exactly: the
+    seed's binary search, each generation's population and its repair
+    rounds over the brood, and the final population."""
+    from repro.core import gsampler_search_grid
+    from repro.core.gsampler import SEED_ITERS, spans
+    wls, hws, batches, _ = _grid_args()
+    cfg = _BE_CFG
+    calls = spans.get("gsampler.wait", {}).get("count", 0)
+    res = gsampler_search_grid(wls, hws, batches, [budget_mb * MB] * 2,
+                               nmax=16, cfg=cfg, top_k=4)
+    for name in ("gsampler.pack", "gsampler.dispatch", "gsampler.unpack"):
+        assert spans[name]["count"] >= 1, name
+    assert spans["gsampler.wait"]["count"] == calls + 1
+    assert res.repair_rounds.shape == (cfg.generations,)
+    assert (res.repair_rounds == rounds).all()
+    assert res.n_evals == 2 * (SEED_ITERS
+                               + cfg.population * (cfg.generations + 1)
+                               + cfg.generations * rounds
+                               * (cfg.population - cfg.elite))
+
+
 def test_teacher_corpus_backend_equivalence():
     from repro.core.accel import ACCEL_ZOO
     from repro.core.dataset import generate_teacher_corpus

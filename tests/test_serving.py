@@ -313,8 +313,19 @@ def test_engine_stats_schema():
                 "compiled_shapes", "chunk_cap", "rows_padded", "tick_dedup",
                 "coalesce_width_hist", "strategy_hit_rate", "strategy_cache",
                 "replicas", "scheduler", "drift",
-                "escalations", "polish_invocations", "polish_improved"):
+                "escalations", "polish_invocations", "polish_improved",
+                "ticks", "guard_iters", "rollout_steps", "spans"):
         assert key in s, key
+    # host spans: one tick, one device call in four phases
+    spans = s["spans"]
+    for name in ("engine.serve", "engine.pack", "engine.dispatch",
+                 "engine.wait", "engine.unpack"):
+        assert spans[name]["seconds"] >= 0.0, name
+    assert spans["engine.wait"]["count"] == s["device_calls"] == 1
+    assert spans["engine.serve"]["count"] == s["ticks"] == 1
+    assert "engine.warmup" not in spans            # never warmed
+    assert s["rollout_steps"] == vgg16().n + 1
+    assert s["guard_iters"] >= 0
     # §17 refinement is off by default: counters exist but never move
     assert (s["escalations"], s["polish_invocations"],
             s["polish_improved"]) == (0, 0, 0)
@@ -323,9 +334,13 @@ def test_engine_stats_schema():
                 "stale_skipped"):
         assert key in s["strategy_cache"], key
     for key in ("queue_depth", "max_queue_depth", "submitted", "rejected",
-                "resolved_at_submit", "flushes"):
+                "resolved_at_submit", "flushes", "queue_wait_s", "queued",
+                "spans"):
         assert key in s["scheduler"], key
     assert s["scheduler"]["submitted"] == 1
+    assert s["scheduler"]["queued"] == 1
+    assert s["scheduler"]["spans"]["scheduler.pump"]["count"] == 1
+    assert s["scheduler"]["spans"]["scheduler.flush"]["count"] == 1
     assert s["replicas"] is None                 # unreplicated engine
     # §15 closed-loop counters: replay/telemetry, drift windows, swaps
     for key in ("replay_depth", "replay_capacity", "replay_total",
@@ -335,6 +350,12 @@ def test_engine_stats_schema():
         assert key in s["drift"], key
     assert s["drift"]["replay_depth"] == 1       # the one served request
     assert s["drift"]["swaps_accepted"] == 0
+    # the span tallies keep counting; warmup gets its own span
+    calls = s["device_calls"]
+    eng.warmup([tiny_cnn()], ACCEL_ZOO["edge"], max_tick=2)
+    s = eng.stats()
+    assert s["spans"]["engine.warmup"]["count"] == 1
+    assert s["spans"]["engine.wait"]["count"] == s["device_calls"] == calls + 2
 
 
 # --- backend protocol -------------------------------------------------------
