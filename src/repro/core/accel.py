@@ -43,7 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 
 __all__ = ["AccelConfig", "PAPER_ACCEL", "ACCEL_ZOO", "HwVec", "HW_FIELDS",
-           "HW_FEATURE_DIM", "as_hw", "stack_hw", "hw_array",
+           "HW_FEATURE_DIM", "as_hw", "stack_hw", "stack_hw_host", "hw_array",
            "hw_from_array", "accel_features", "accel_from_features"]
 
 MB = float(2 ** 20)
@@ -197,6 +197,15 @@ def stack_hw(hw, C: int) -> HwVec:
         raise ValueError(f"stacked HwVec has {v.npe.shape[0]} rows, "
                          f"expected {C}")
     return v
+
+
+def stack_hw_host(hws) -> HwVec:
+    """:func:`stack_hw` of a sequence of ``AccelConfig``s, built on the
+    host: an ``HwVec`` of numpy ``[C]`` f32 columns, taken from one
+    ``[C, HW_FEATURE_DIM]`` table in ``HW_FIELDS`` order."""
+    table = np.array([[float(getattr(h, f)) for f in HW_FIELDS]
+                      for h in hws], np.float32)
+    return HwVec(*np.ascontiguousarray(table.T))
 
 
 def accel_features(hw) -> jax.Array:

@@ -43,7 +43,8 @@ from .accel import AccelConfig, HwVec, as_hw, stack_hw
 
 __all__ = ["SYNC", "CostOut", "evaluate", "evaluate_population",
            "evaluate_population_stats", "baseline_no_fusion", "prefix_trace",
-           "pack_workload", "stack_workloads", "PrefixConsts", "PrefixCarry",
+           "pack_workload", "pack_workload_host", "pack_grid_host",
+           "stack_workloads", "PrefixConsts", "PrefixCarry",
            "prefix_consts", "prefix_init", "prefix_step", "prefix_out",
            "prefix_probe_peak", "prefix_scan", "evaluate_grid",
            "evaluate_grid_stats", "baseline_grid", "finalize_groups",
@@ -96,20 +97,39 @@ class CostOut(NamedTuple):
     n_groups: jax.Array     # number of fused groups
 
 
-def pack_workload(workload, hw: AccelConfig, nmax: int = 64) -> dict[str, jnp.ndarray]:
-    """Device-ready workload arrays (bytes scaled by hw.bytes_per_elem).
+def pack_workload_host(workload, hw: AccelConfig,
+                       nmax: int = 64) -> dict[str, np.ndarray]:
+    """Packed workload as host numpy arrays (bytes scaled by
+    hw.bytes_per_elem): f32 A/W/F/OE/UC/SHAPE6, int32 SKIP and ``n``, bool
+    ``mask``.
 
     ``BPE`` records the pack-time bytes/elem so the evaluators can rescale
     A/W when serving the same packing on an accelerator with a different
     datatype (DESIGN §11) — identity when they match."""
     arrs = workload.arrays(nmax, bytes_per_elem=hw.bytes_per_elem)
-    out = {k: jnp.asarray(v, dtype=jnp.float32) for k, v in arrs.items()
+    out = {k: np.asarray(v, dtype=np.float32) for k, v in arrs.items()
            if k in ("A", "W", "F", "OE", "UC", "SHAPE6")}
-    out["SKIP"] = jnp.asarray(arrs["SKIP"], dtype=jnp.int32)
-    out["mask"] = jnp.asarray(arrs["mask"])
-    out["n"] = jnp.asarray(arrs["n"], dtype=jnp.int32)
-    out["BPE"] = jnp.asarray(hw.bytes_per_elem, jnp.float32)
+    out["SKIP"] = np.asarray(arrs["SKIP"], dtype=np.int32)
+    out["mask"] = np.asarray(arrs["mask"], dtype=bool)
+    out["n"] = np.asarray(arrs["n"], dtype=np.int32)
+    out["BPE"] = np.asarray(hw.bytes_per_elem, np.float32)
     return out
+
+
+def pack_workload(workload, hw: AccelConfig, nmax: int = 64) -> dict[str, jnp.ndarray]:
+    """Device-ready workload arrays: the transfer of
+    :func:`pack_workload_host`'s rows."""
+    return {k: jnp.asarray(v)
+            for k, v in pack_workload_host(workload, hw, nmax).items()}
+
+
+def pack_grid_host(workloads: list, hws: list,
+                   nmax: int = 64) -> dict[str, np.ndarray]:
+    """:func:`stack_workloads` of ``pack_workload(workloads[c], hws[c])``,
+    built on the host: one numpy array per leaf, so a jitted grid program
+    moves each leaf to the device once, as its argument."""
+    rows = [pack_workload_host(w, h, nmax) for w, h in zip(workloads, hws)]
+    return {k: np.stack([r[k] for r in rows]) for k in rows[0]}
 
 
 def _scaled_AW(wl: dict, hw: HwVec) -> tuple[jax.Array, jax.Array]:

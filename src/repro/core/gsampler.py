@@ -25,7 +25,7 @@ import numpy as np
 
 from ..obs import span
 from . import cost_model as cm
-from .accel import AccelConfig, HwVec, stack_hw
+from .accel import AccelConfig, HwVec, stack_hw, stack_hw_host
 
 __all__ = ["GSamplerConfig", "GSamplerResult", "gsampler_search",
            "naive_uniform_mb", "GridTeacherResult", "gsampler_search_grid"]
@@ -434,6 +434,30 @@ def _ga_grid(key, wls, batches, budgets, hw,
                 repair_rounds=repair_rounds)
 
 
+def _prepare_grid(workloads, hw, C: int, nmax: int, packed):
+    """The grid front door's packing contract: host ``AccelConfig``s pack
+    on the host, as numpy, so the jitted program's argument transfer moves
+    each leaf once; an already-vectorized ``hw`` requires ``packed=``.
+    Returns (stacked workloads, per-condition ``HwVec``)."""
+    if isinstance(hw, AccelConfig) or (
+            isinstance(hw, (list, tuple)) and not isinstance(hw, HwVec)):
+        hws = list(hw) if isinstance(hw, (list, tuple)) else [hw] * C
+        if len(hws) != C:
+            raise ValueError(f"got {len(hws)} accelerators for {C} "
+                             "conditions")
+        if packed is None:
+            if workloads is None:
+                raise ValueError("pass workloads= or packed=")
+            packed = cm.pack_grid_host(workloads, hws, nmax)
+        return packed, stack_hw_host(hws)
+    # already-vectorized hardware (stacked HwVec / raw [C, F] array):
+    # packing needs host AccelConfigs, so the caller must supply it
+    if packed is None:
+        raise ValueError("vectorized hw (HwVec / raw array) requires "
+                         "`packed=` — pack_workload needs AccelConfigs")
+    return packed, stack_hw(hw, C)
+
+
 def gsampler_search_grid(workloads: list, hw, batches,
                          budgets_bytes, *, nmax: int = 64,
                          cfg: GSamplerConfig = GSamplerConfig(),
@@ -460,27 +484,9 @@ def gsampler_search_grid(workloads: list, hw, batches,
     t0 = time.perf_counter()
     C = len(workloads)
     with span("gsampler.pack", spans):
-        if isinstance(hw, AccelConfig) or (
-                isinstance(hw, (list, tuple)) and not isinstance(hw, HwVec)):
-            hws = list(hw) if isinstance(hw, (list, tuple)) else [hw] * C
-            assert len(hws) == C
-            if packed is None:
-                packed = cm.stack_workloads(
-                    [cm.pack_workload(w, h, nmax)
-                     for w, h in zip(workloads, hws)])
-            hwv = stack_hw(hws, C)
-        else:
-            # already-vectorized hardware (stacked HwVec / raw [C, F]
-            # array): packing needs host AccelConfigs, so the caller must
-            # supply it
-            if packed is None:
-                raise ValueError("vectorized hw (HwVec / raw array) requires "
-                                 "`packed=` — pack_workload needs "
-                                 "AccelConfigs")
-            hwv = stack_hw(hw, C)
-        wls = packed
-        batches = jnp.asarray(np.asarray(batches, np.float32))
-        budgets = jnp.asarray(np.asarray(budgets_bytes, np.float32))
+        wls, hwv = _prepare_grid(workloads, hw, C, nmax, packed)
+        batches = np.asarray(batches, np.float32)
+        budgets = np.asarray(budgets_bytes, np.float32)
         key = jax.random.PRNGKey(cfg.seed)
     with span("gsampler.dispatch", spans):
         out = _ga_grid(key, wls, batches, budgets, hwv, cfg, top_k,

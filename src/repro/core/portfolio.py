@@ -42,9 +42,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import cost_model as cm
-from .accel import AccelConfig, HwVec, stack_hw
 from .env import encode_action
-from .gsampler import _fitness_jnp
+from .gsampler import _fitness_jnp, _prepare_grid
 
 __all__ = ["PortfolioConfig", "PortfolioResult", "de_search_grid",
            "cmaes_search_grid"]
@@ -227,30 +226,6 @@ def _portfolio_grid_jit(keys, wls, batches, budgets, hw, y0,
                 baseline_latency=base)
 
 
-def _prepare_grid(workloads, hw, batches, budgets_bytes, nmax, packed):
-    """Pack/stack the condition grid — the ``gsampler_search_grid``
-    front-door contract: host ``AccelConfig``s pack on demand; an
-    already-vectorized ``hw`` requires ``packed=``."""
-    C = len(batches)
-    if isinstance(hw, AccelConfig) or (
-            isinstance(hw, (list, tuple)) and not isinstance(hw, HwVec)):
-        hws = list(hw) if isinstance(hw, (list, tuple)) else [hw] * C
-        assert len(hws) == C
-        if packed is None:
-            if workloads is None:
-                raise ValueError("pass workloads= or packed=")
-            packed = cm.stack_workloads(
-                [cm.pack_workload(w, h, nmax)
-                 for w, h in zip(workloads, hws)])
-        hwv = stack_hw(hws, C)
-    else:
-        if packed is None:
-            raise ValueError("vectorized hw (HwVec / raw array) requires "
-                             "`packed=` — pack_workload needs AccelConfigs")
-        hwv = stack_hw(hw, C)
-    return packed, hwv
-
-
 def _search_grid(method: str, workloads, hw, batches, budgets_bytes, *,
                  nmax, cfg, init_strategies, salts, packed,
                  evaluator) -> PortfolioResult:
@@ -258,9 +233,7 @@ def _search_grid(method: str, workloads, hw, batches, budgets_bytes, *,
     batches = np.asarray(batches, np.float32)
     budgets = np.asarray(budgets_bytes, np.float32)
     C = len(batches)
-    wls, hwv = _prepare_grid(workloads, hw, batches, budgets_bytes, nmax,
-                             packed)
-    wls = {k: jnp.asarray(v) for k, v in wls.items()}
+    wls, hwv = _prepare_grid(workloads, hw, C, nmax, packed)
     P = wls["A"].shape[-1]
     if salts is None:
         salts = np.arange(C)

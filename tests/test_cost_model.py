@@ -103,6 +103,39 @@ else:
             _check_invariants(_seeded_strategy(rng, WL[wname].n), wname)
 
 
+def test_host_grid_pack_equals_device_pack():
+    """The host packer's grid equals the stacked device packings leaf by
+    leaf, across networks of different ``n`` and accelerators of different
+    bytes/elem; the numpy hardware stack equals ``stack_hw``."""
+    from repro.core.accel import (ACCEL_ZOO, HW_FIELDS, stack_hw,
+                                  stack_hw_host)
+    from repro.workloads import tiny_cnn
+    edge, dc = ACCEL_ZOO["edge"], ACCEL_ZOO["datacenter"]
+    assert edge.bytes_per_elem != dc.bytes_per_elem
+    wls = [tiny_cnn(), resnet18(), tiny_cnn(), resnet18()]
+    hws = [edge, edge, dc, dc]
+    host = cm.pack_grid_host(wls, hws, 32)
+    dev = cm.stack_workloads([cm.pack_workload(w, h, 32)
+                              for w, h in zip(wls, hws)])
+    assert list(host) == list(dev)
+    dtypes = dict(SKIP=np.int32, n=np.int32, mask=np.bool_)
+    for k, d in dev.items():
+        d = np.asarray(d)
+        assert isinstance(host[k], np.ndarray), k
+        assert host[k].dtype == d.dtype == dtypes.get(k, np.float32), k
+        assert host[k].shape == d.shape, k
+        np.testing.assert_array_equal(host[k], d, err_msg=k)
+    assert host["n"].tolist() == [w.n for w in wls]
+    assert host["BPE"].tolist() == [h.bytes_per_elem for h in hws]
+
+    hv, dv = stack_hw_host(hws), stack_hw(hws, len(hws))
+    for f in HW_FIELDS:
+        a, b = getattr(hv, f), np.asarray(getattr(dv, f))
+        assert isinstance(a, np.ndarray), f
+        assert a.dtype == b.dtype == np.float32 and a.shape == b.shape, f
+        np.testing.assert_array_equal(a, b, err_msg=f)
+
+
 def test_baseline_matches_ref():
     for n, w in WL.items():
         b = cm.baseline_no_fusion(PACKED[n], 64.0, HW)

@@ -1,4 +1,5 @@
 """G-Sampler + baseline searcher behaviour."""
+import jax
 import numpy as np
 import pytest
 
@@ -119,6 +120,45 @@ def test_repair_rounds_and_exact_n_evals(budget_mb, rounds):
                                + cfg.population * (cfg.generations + 1)
                                + cfg.generations * rounds
                                * (cfg.population - cfg.elite))
+
+
+def test_grid_host_pack_equals_device_pack():
+    """A grid of host ``AccelConfig``s is packed on the host; the search
+    must return exactly what the same call given the device-packed
+    ``packed=`` dict and a stacked ``HwVec`` returns, and the numpy and
+    device arguments must share one compiled program."""
+    from repro.core import cost_model as cm, gsampler_search_grid
+    from repro.core.accel import ACCEL_ZOO, stack_hw
+    from repro.workloads import tiny_cnn
+    wls = [tiny_cnn(), resnet18(), tiny_cnn(), resnet18()]
+    hws = [ACCEL_ZOO["edge"], ACCEL_ZOO["edge"], ACCEL_ZOO["datacenter"],
+           ACCEL_ZOO["datacenter"]]
+    batches, budgets = [8.0, 16.0, 8.0, 16.0], [2 * MB, 8 * MB, 4 * MB,
+                                                16 * MB]
+    kw = dict(nmax=32, cfg=_BE_CFG, top_k=4, evaluator="xla")
+    host = gsampler_search_grid(wls, hws, batches, budgets, **kw)
+    packed = cm.stack_workloads([cm.pack_workload(w, h, 32)
+                                 for w, h in zip(wls, hws)])
+    hwv = stack_hw(hws, len(hws))
+    compiles = []
+
+    def on_compile(event, secs, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(on_compile)
+    try:
+        dev = gsampler_search_grid(wls, hwv, batches, budgets,
+                                   packed=packed, **kw)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_compile)
+    assert compiles == []
+    for field in ("strategies", "latency", "peak_mem", "speedup", "valid",
+                  "history", "baseline_latency", "repair_rounds"):
+        np.testing.assert_array_equal(getattr(host, field),
+                                      getattr(dev, field), err_msg=field)
+    assert host.n_evals == dev.n_evals
+    assert host.valid.any()
 
 
 def test_teacher_corpus_backend_equivalence():
