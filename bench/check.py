@@ -1,7 +1,9 @@
 """The comparison that decides ``correct``: what the timed path served,
-against the plain reference (``reference.py``), each number beside its
-limit.  The limits live in the configuration's file, under ``limits``,
-with the readings they were set from in PERF.md.
+against the plain reference that the cell's configuration names
+(``harness.Spec.reference``: ``bench/reference.py`` unless the
+configuration's ``reference`` names another file), passed in as ``R``,
+each number beside its limit.  The limits live in the configuration's
+file, under ``limits``, with the readings they were set from in PERF.md.
 
 Numbers compared:
 
@@ -26,8 +28,6 @@ from __future__ import annotations
 
 import numpy as np
 
-from . import reference as R
-
 
 def _fits_variants(peaks_t: np.ndarray, budget: float, eps: float) -> list:
     """The guard's fit vector at one step; two where a probe's peak lies
@@ -43,7 +43,7 @@ def _encode(strategy: np.ndarray, batch: int) -> np.ndarray:
     return np.where(strategy < 0, -0.5, strategy / float(batch))
 
 
-def prepare(samples: list, eps: float) -> list:
+def prepare(R, samples: list, eps: float) -> list:
     """Reference environment along each served trajectory: observations,
     encoded actions and the guard's fit vectors."""
     out = []
@@ -66,7 +66,7 @@ def prepare(samples: list, eps: float) -> list:
     return out
 
 
-def head_outputs(items: list, params, model: dict, quant: bool
+def head_outputs(R, items: list, params, model: dict, quant: bool
                  ) -> np.ndarray:
     """[K, max_steps] teacher-forced head outputs of the reference DT."""
     import jax.numpy as jnp
@@ -90,7 +90,7 @@ def head_outputs(items: list, params, model: dict, quant: bool
     return np.asarray(y, np.float64)
 
 
-def pred_gap(items: list, y_ref: np.ndarray, y_other=None) -> float:
+def pred_gap(R, items: list, y_ref: np.ndarray, y_other=None) -> float:
     """Widest gap of the served actions (or, with ``y_other``, of the
     actions those head outputs give) from the reference's outputs."""
     worst = 0.0
@@ -108,7 +108,7 @@ def pred_gap(items: list, y_ref: np.ndarray, y_other=None) -> float:
     return worst
 
 
-def cost_numbers(items: list, served: list, eps: float, dt=np.float64
+def cost_numbers(R, items: list, served: list, eps: float, dt=np.float64
                  ) -> dict:
     """``cost_rel`` and ``valid_flips`` of served (latency, peak, speedup,
     valid) tuples against the reference evaluated in ``dt``; with ``dt``
@@ -137,35 +137,35 @@ def cost_numbers(items: list, served: list, eps: float, dt=np.float64
     return {"cost_rel": rel, "valid_flips": flips}
 
 
-def check_served(samples: list, served: list, params, model: dict,
+def check_served(R, samples: list, served: list, params, model: dict,
                  limits: dict, hit_mismatch: int) -> dict:
     """The numbers of a served-model cell.  ``samples`` are (request,
     strategy) pairs and ``served`` the matching (latency, peak, speedup,
     valid) tuples."""
-    items = prepare(samples, limits["cost_rel"])
-    y_ref = head_outputs(items, params, model, quant=False)
-    numbers = {"pred_gap": pred_gap(items, y_ref),
-               **cost_numbers(items, served, limits["cost_rel"]),
+    items = prepare(R, samples, limits["cost_rel"])
+    y_ref = head_outputs(R, items, params, model, quant=False)
+    numbers = {"pred_gap": pred_gap(R, items, y_ref),
+               **cost_numbers(R, items, served, limits["cost_rel"]),
                "illegal": sum(not it["legal"] for it in items),
                "hit_mismatch": hit_mismatch}
     return numbers
 
 
-def control_served(samples: list, params, model: dict, limits: dict
+def control_served(R, samples: list, params, model: dict, limits: dict
                    ) -> dict:
     """The control's numbers on the same requests: the reference DT with
     float8 matmul operands in the program's place (the action it puts
     first at each step of the served trajectory), and the cost model in
     bfloat16."""
-    items = prepare(samples, limits["cost_rel"])
-    y_ref = head_outputs(items, params, model, quant=False)
-    y_low = head_outputs(items, params, model, quant=True)
-    return {"pred_gap": pred_gap(items, y_ref, y_low),
-            **cost_numbers(items, [(None,) * 4] * len(items),
+    items = prepare(R, samples, limits["cost_rel"])
+    y_ref = head_outputs(R, items, params, model, quant=False)
+    y_low = head_outputs(R, items, params, model, quant=True)
+    return {"pred_gap": pred_gap(R, items, y_ref, y_low),
+            **cost_numbers(R, items, [(None,) * 4] * len(items),
                            limits["cost_rel"], dt=R.BF16)}
 
 
-def check_search(samples: list, limits: dict, dt=np.float64) -> dict:
+def check_search(R, samples: list, limits: dict, dt=np.float64) -> dict:
     """The numbers of a search cell.  ``samples``: (condition request,
     elite strategies [K, P], latency [K], peak [K], speedup [K], valid [K])
     per condition.  With ``dt`` below float64 the elites' costs are the

@@ -26,14 +26,14 @@ sys.path[0] = str(pathlib.Path(__file__).resolve().parent.parent)
 
 def readings(spec, driver, seed: int, seconds: float, compiles) -> dict:
     from bench import check, harness
-    from bench.reference import BF16
+    R = spec.reference
     _, numbers, rec = driver.run(spec, seed, seconds, False,
                                  harness.now(), compiles)
     limits = spec.config["limits"]
     if spec.mix["loop"] == "search":
-        control = check.check_search(rec.sample, limits, dt=BF16)
+        control = check.check_search(R, rec.sample, limits, dt=R.BF16)
     else:
-        control = check.control_served(rec.sample, rec.params,
+        control = check.control_served(R, rec.sample, rec.params,
                                        rec.model, limits)
     return {"seed": seed, "program": numbers, "control": control}
 

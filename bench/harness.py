@@ -1,11 +1,13 @@
 """What every cell's run shares: reading ``BENCHMARK.json`` and the files
-it names, the chip check, compile accounting, the weights, the profiler
-window, the per-layer metric readers and the result line."""
+it names, the cell's serving settings and plain reference, the chip check,
+compile accounting, the weights, the profiler window, the per-layer metric
+readers and the result line."""
 from __future__ import annotations
 
 import importlib.util
 import json
 import pathlib
+import re
 import shutil
 import sys
 import time
@@ -13,18 +15,43 @@ import time
 ROOT = pathlib.Path(__file__).resolve().parent.parent
 BENCH = ROOT / "bench"
 TRACE_DIR = ROOT / ".bench_trace"
+REFERENCE = "bench/reference.py"   # a configuration's reference by default
 TRACE_START = 0.3      # share of the window before the traced stretch
 TRACE_SECONDS = 1.0    # length of the traced stretch
 HEAD_BIAS = 0.3        # encoded action at the centre of the head's outputs
 HEAD_SPREAD = 0.4      # their spread
+BASE_KEY = 0           # the key of the one mapper that seeds reorder
 
 
 def log(msg: str) -> None:
     print(msg, flush=True)
 
 
+def load_module(path: pathlib.Path):
+    """The Python file at ``path``, loaded once per process."""
+    name = "bench_file_" + re.sub(r"\W", "_", str(path.relative_to(ROOT)))
+    if name not in sys.modules:
+        spec = importlib.util.spec_from_file_location(name, path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        sys.modules[name] = mod
+    return sys.modules[name]
+
+
+def load_reference(path: str):
+    """The plain reference module a configuration names: a file under
+    ``bench/``, given relative to the checkout's root."""
+    file = (ROOT / path).resolve()
+    if BENCH not in file.parents or not file.is_file():
+        raise SystemExit(f"bench: reference {path!r} is no file under "
+                         f"bench/")
+    return load_module(file)
+
+
 class Spec:
-    """One cell of ``BENCHMARK.json`` with its configuration and mix."""
+    """One cell of ``BENCHMARK.json`` with its configuration, its mix and
+    the plain reference its configuration names (``reference``, by default
+    ``bench/reference.py``)."""
 
     def __init__(self, workload: str):
         from . import generate
@@ -39,6 +66,20 @@ class Spec:
             (ROOT / configs[self.cell["config"]]["file"]).read_text())
         self.mix = generate.load(self.cell["traffic"])
         self.chips = int(self.cell["chips"])
+        self.reference = load_reference(self.config.get("reference",
+                                                        REFERENCE))
+
+    def serving(self) -> dict:
+        """The cell's serving settings.  The configuration's ``serving``
+        block describes the deployment on one chip; a cell of n > 1 chips
+        runs n data-parallel replicas of it behind one engine
+        (``replicas`` n, ``max_coalesce`` n times the configuration's:
+        each chip takes as many lanes a tick as one chip alone)."""
+        sc = dict(self.config["serving"])
+        if self.chips > 1:
+            sc.update(replicas=self.chips,
+                      max_coalesce=sc["max_coalesce"] * self.chips)
+        return sc
 
     def end_to_end(self) -> list[dict]:
         return [m for m in self.bench["end_to_end"]
@@ -149,14 +190,21 @@ def dt_shapes(model: dict) -> dict:
 
 
 def dt_weights(model: dict, seed: int):
-    """Random weights from the seed, made on the device in one jitted
-    call: dense kernels N(0, 1/fan_in), embeddings N(0, 0.02^2), and
-    biases and norm offsets N(0, 0.02^2) so that every term of the
-    forward pass is exercised.  The action head is centred: its bias is
-    HEAD_BIAS and its kernel N(0, HEAD_SPREAD^2/fan_in), so that a seed's
-    mapper proposes micro-batches as well as syncs and the guard does its
-    work; left to chance, more seeds give a mapper that syncs
-    everywhere."""
+    """Weights from the seed, made on the device in one jitted call.
+
+    One mapper, drawn from the fixed key ``BASE_KEY``: dense kernels
+    N(0, 1/fan_in), embeddings N(0, 0.02^2), and biases and norm offsets
+    N(0, 0.02^2) so that every term of the forward pass is exercised.  The
+    action head is centred: its bias is HEAD_BIAS and its kernel
+    N(0, HEAD_SPREAD^2/fan_in), so that the mapper proposes micro-batches
+    as well as syncs and the guard does its work.  The seed then reorders
+    the mapper's units: the residual features, each block's heads, the
+    features within each head (queries with keys, values with the output
+    projection) and each block's feed-forward units.  Every seed's weights
+    differ and compute the same function, up to the rounding of a sum
+    taken in another order, so every seed gives the guard and the repair
+    the same work; left to chance, one seed's mapper can do three times
+    the guard work of another's."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -164,6 +212,8 @@ def dt_weights(model: dict, seed: int):
                          and isinstance(x[1], str))
     leaves, treedef = jax.tree_util.tree_flatten(dt_shapes(model),
                                                  is_leaf=is_leaf)
+    d, dff, heads = model["d_model"], model["d_ff"], model["n_heads"]
+    hd = d // heads
 
     def init(key):
         keys = jax.random.split(key, len(leaves))
@@ -182,9 +232,56 @@ def dt_weights(model: dict, seed: int):
                 out.append(0.02 * z)
         return jax.tree_util.tree_unflatten(treedef, out)
 
+    def head_columns(order, key):
+        """Columns of a [d, d] projection: the heads in ``order``, and the
+        features of each head in an order drawn from ``key``."""
+        within = jax.vmap(lambda k: jax.random.permutation(k, hd))(
+            jax.random.split(key, heads))
+        return (order[:, None] * hd + within).reshape(d)
+
+    def reorder(p, key):
+        kr, kb = jax.random.split(key)
+        r = jax.random.permutation(kr, d)         # residual features
+
+        def out_d(lin):                           # output width d
+            lin = dict(lin, w=lin["w"][:, r])
+            if "b" in lin:
+                lin["b"] = lin["b"][r]
+            return lin
+
+        norm = lambda n: {"g": n["g"][r], "b": n["b"][r]}
+        q = dict(p)
+        for name in ("emb_r", "emb_s", "emb_a", "emb_h"):
+            q[name] = out_d(p[name])
+        q["time"] = {"emb": p["time"]["emb"][:, r]}
+        q["type"] = {"emb": p["type"]["emb"][:, r]}
+        q["ln_f"] = norm(p["ln_f"])
+        q["head"] = dict(p["head"], w=p["head"]["w"][r, :])
+        blocks = []
+        for blk, k in zip(p["blocks"], jax.random.split(kb,
+                                                        len(p["blocks"]))):
+            kh, kqk, kvo, kff = jax.random.split(k, 4)
+            order = jax.random.permutation(kh, heads)
+            cqk, cvo = head_columns(order, kqk), head_columns(order, kvo)
+            f = jax.random.permutation(kff, dff)
+            a, m = blk["attn"], blk["mlp"]
+            blocks.append({
+                "ln1": norm(blk["ln1"]), "ln2": norm(blk["ln2"]),
+                "attn": {"q": {"w": a["q"]["w"][r][:, cqk]},
+                         "k": {"w": a["k"]["w"][r][:, cqk]},
+                         "v": {"w": a["v"]["w"][r][:, cvo]},
+                         "o": {"w": a["o"]["w"][cvo][:, r]}},
+                "mlp": {"up": {"w": m["up"]["w"][r][:, f],
+                               "b": m["up"]["b"][f]},
+                        "down": {"w": m["down"]["w"][f][:, r],
+                                 "b": m["down"]["b"][r]}}})
+        q["blocks"] = blocks
+        return q
+
     key = jax.random.PRNGKey(int(np.random.default_rng([seed, 1])
                                  .integers(2 ** 31)))
-    return jax.block_until_ready(jax.jit(init)(key))
+    return jax.block_until_ready(jax.jit(
+        lambda k: reorder(init(jax.random.PRNGKey(BASE_KEY)), k))(key))
 
 
 class Tracer:
@@ -217,14 +314,19 @@ class Tracer:
         import jax
         if self.state == "on":
             self._span.__exit__(None, None, None)
+            t = now()
             jax.profiler.stop_trace()
+            log(f"profiler stop {now() - t:.3f} s")
             self.state = "done"
 
     def summary(self) -> dict | None:
         from . import trace
         if self.state != "done":
             return None
-        return trace.reduce(trace.extract(trace.load(str(TRACE_DIR))))
+        t = now()
+        out = trace.reduce(trace.extract(trace.load(str(TRACE_DIR))))
+        log(f"trace read and reduced in {now() - t:.3f} s")
+        return out
 
 
 def span(name: str):
@@ -238,13 +340,7 @@ def read_metrics(metrics: list[dict], rec) -> dict:
     left out."""
     out = {}
     for m in metrics:
-        path = BENCH / "metrics" / f"{m['name']}.py"
-        spec = importlib.util.spec_from_file_location(
-            "bench_metric_" + m["name"].replace(".", "_").replace("-", "_"),
-            path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        v = mod.read(rec)
+        v = load_module(BENCH / "metrics" / f"{m['name']}.py").read(rec)
         if v is not None:
             out[m["name"]] = {"value": float(v), "unit": m["unit"]}
     return out

@@ -1,4 +1,5 @@
-"""Share of the traced stretch in which no operation ran on the device."""
+"""Share of the traced stretch in which no operation ran on the device,
+averaged over the chips."""
 
 
 def read(rec):

@@ -1,5 +1,6 @@
 """Device time per call of the fused rollout program (``core/infer.py``
-``_fused_batch``), from the trace."""
+``_fused_batch``), averaged over the chips (on several, each holds its
+share of the tick's lanes), from the trace."""
 from bench.trace import module_time
 
 

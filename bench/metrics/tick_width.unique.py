@@ -1,5 +1,6 @@
-"""Mean true lanes per device call of the engine (``serving/engine.py``),
-from its ``coalesce_width_hist`` over the window."""
+"""Mean true lanes per device call of the engine (``serving/engine.py``;
+all chips' lanes together where they are replicas), from its
+``coalesce_width_hist`` over the window."""
 
 
 def read(rec):

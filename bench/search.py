@@ -88,7 +88,8 @@ def run(spec, seed: int, seconds: float, tracing: bool, t_process: float,
             samples.append((req, res.strategies[c], res.latency[c],
                             res.peak_mem[c], res.speedup[c], res.valid[c]))
     t_ref = now()
-    numbers = check.check_search(samples, spec.config["limits"])
+    numbers = check.check_search(spec.reference, samples,
+                                 spec.config["limits"])
     rec.sample = samples
     log(f"reference: {len(samples)} conditions x {ga['top_k']} elites, "
         f"{now() - t_ref:.2f} s")

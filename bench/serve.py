@@ -28,7 +28,7 @@ class Serving:
             d_model=self.model["d_model"], d_ff=self.model["d_ff"],
             max_steps=self.model["max_steps"], hw_dim=self.model["hw_dim"])
         self.params = harness.dt_weights(self.model, seed)
-        sc = spec.config["serving"]
+        sc = spec.serving()
         config = repro.ServingConfig(
             repair=sc["repair"], polish=sc["polish"], escalate=sc["escalate"],
             nmax_buckets=tuple(sc["nmax_buckets"]),
@@ -213,10 +213,11 @@ def run(spec, seed: int, seconds: float, tracing: bool, t_process: float,
     del s.sched, s.engine, sched, engine
     t_ref = now()
     numbers = check.check_served(
-        [(q, r.strategy) for q, r in sample],
+        spec.reference, [(q, r.strategy) for q, r in sample],
         [(r.latency, r.peak_mem, r.speedup, r.valid) for _, r in sample],
         s.params, s.model, spec.config["limits"], mismatch)
-    numbers["illegal"] += _illegal_unsampled(misses, sample)
+    numbers["illegal"] += _illegal_unsampled(spec.reference, misses,
+                                              sample)
     rec.sample = [(q, r.strategy) for q, r in sample]
     rec.params = s.params
     log(f"reference: {len(sample)} requests, "
@@ -227,9 +228,8 @@ def run(spec, seed: int, seconds: float, tracing: bool, t_process: float,
     return e2e, numbers, rec
 
 
-def _illegal_unsampled(misses: list, sample: list) -> int:
+def _illegal_unsampled(R, misses: list, sample: list) -> int:
     """Legality of every device-served answer outside the sample."""
-    from .reference import legal
     seen = {id(r) for _, r in sample}
-    return sum(not legal(r.strategy, q.workload.n, int(q.batch))
+    return sum(not R.legal(r.strategy, q.workload.n, int(q.batch))
                for q, r in misses if id(r) not in seen)

@@ -2,12 +2,10 @@
 harness's look for a chip is skipped, everything else runs."""
 from __future__ import annotations
 
-import json
 import os
 import pathlib
 import sys
 import time
-from types import SimpleNamespace
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 ROOT = pathlib.Path(__file__).resolve().parents[2]
@@ -16,30 +14,33 @@ sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 import pytest  # noqa: E402
 
 
-def small_spec(kind: str) -> SimpleNamespace:
-    """A cell's configuration and mix cut to CPU size: the paper's widths
-    with a 16-step trajectory on tiny_cnn for serving, a short search over
-    two small networks for G-Sampler."""
-    from bench import generate
-    cfgs = ROOT / "bench" / "configs"
+CELLS = {"search": "gs-sweep64", "closed": "dt-unique-closed",
+         "open": "dt-unique-closed", "closed4": "dt-unique-closed-4chip"}
+
+
+def small_spec(kind: str):
+    """A cell's spec (``harness.Spec``) with its configuration and mix cut
+    to CPU size: the paper's widths with a 16-step trajectory on tiny_cnn
+    for serving (``closed4``: the four-chip cell, 4 lanes a chip), a short
+    search over two small networks for G-Sampler."""
+    from bench import generate, harness
+    spec = harness.Spec(CELLS[kind])
+    cfg, mix = spec.config, spec.mix
     if kind == "search":
-        cfg = json.loads((cfgs / "gsampler-paper.json").read_text())
         cfg["search"]["gsampler"].update(population=8, generations=4)
-        mix = generate.load("sweep64")
         mix.update(networks=["tiny_cnn", "vgg16"], accels=["edge", "mobile"],
                    budgets_per_condition=2)
     else:
-        cfg = json.loads((cfgs / "dnnfuser-dt.json").read_text())
         cfg["model"]["max_steps"] = 16
         cfg["serving"].update(nmax_buckets=[8, 16], max_coalesce=4)
-        if kind == "closed":
-            mix = generate.load("unique-closed")
-            mix.update(networks=["tiny_cnn"], callers=8, batches=[16, 32])
-        else:
-            mix = generate.load("zipf-open")
+        if kind == "open":
+            spec.mix = mix = generate.load("zipf-open")
             mix["grid"]["networks"] = ["tiny_cnn"]
             mix["rate_rps"] = 200
-    return SimpleNamespace(config=cfg, mix=mix, chips=1)
+        else:
+            mix.update(networks=["tiny_cnn"], callers=8 * spec.chips,
+                       batches=[16, 32])
+    return spec
 
 
 @pytest.fixture

@@ -7,9 +7,11 @@ from __future__ import annotations
 
 import json
 import os
+import pathlib
 import re
 import subprocess
 import sys
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
@@ -57,6 +59,52 @@ def test_bounds_and_units_keep_to_the_contract():
     assert 1 <= b["run_seconds"] <= 51
     four = sum(w["chips"] == 4 for w in b["workloads"])
     assert four <= max(1, len(b["workloads"]) // 2)
+
+
+@pytest.mark.parametrize("cell", [w["name"] for w in bench_json()
+                                  ["workloads"] if w["config"]
+                                  == "dnnfuser-dt"])
+def test_serving_settings_are_the_configurations_per_chip(cell):
+    from bench import harness
+    spec = harness.Spec(cell)
+    own = spec.config["serving"]
+    got = spec.serving()
+    if spec.chips == 1:
+        assert got == own and got["replicas"] is None
+    else:
+        assert spec.chips == 4
+        assert got["replicas"] == 4 and got["max_coalesce"] == 64
+        assert {k: v for k, v in got.items()
+                if k not in ("replicas", "max_coalesce")} == {
+            k: v for k, v in own.items()
+            if k not in ("replicas", "max_coalesce")}
+
+
+def test_the_reference_is_the_one_the_configuration_names(monkeypatch,
+                                                          tmp_path):
+    """Without ``reference`` a configuration gets ``bench/reference.py``;
+    one that names a copy under ``bench/tests`` gets that copy."""
+    import shutil
+    from bench import harness
+    for cell in ("dt-unique-closed", "gs-sweep64"):
+        ref = harness.Spec(cell).reference
+        assert pathlib.Path(ref.__file__) == ROOT / "bench" / "reference.py"
+        assert not hasattr(ref, "MARK")
+    b = bench_json()
+    cfg = json.loads((ROOT / "bench/configs/dnnfuser-dt.json").read_text())
+    cfg["reference"] = "bench/tests/reference_copy.py"
+    (tmp_path / "bench/configs").mkdir(parents=True)
+    (tmp_path / "bench/tests").mkdir()
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(b))
+    (tmp_path / "bench/configs/dnnfuser-dt.json").write_text(
+        json.dumps(cfg))
+    shutil.copy(ROOT / cfg["reference"], tmp_path / cfg["reference"])
+    monkeypatch.setattr(harness, "ROOT", tmp_path)
+    monkeypatch.setattr(harness, "BENCH", tmp_path / "bench")
+    ref = harness.Spec("dt-unique-closed").reference
+    assert ref.MARK == cfg["reference"] and ref.legal is not None
+    with pytest.raises(SystemExit):
+        harness.load_reference("BENCHMARK.json")
 
 
 def test_same_seed_same_stream_and_fixed_popularity():
@@ -132,6 +180,46 @@ def test_trace_reduction_on_a_hand_made_trace():
         {"while": 300e-9, "copy": 300e-9, "fusion": 200e-9})
 
 
+TWO_CHIPS = {
+    "devices": {f"/device:TPU:{i}": {
+        "modules": [["jit__fused_batch(7)", 1000, 400],
+                    ["jit__fused_batch(7)", 2000, 300],
+                    ["jit__other(1)", 2500, 100]],
+        "ops": [["%while.3", 1000, 400], ["%all-reduce.2", 1100, 20 + i],
+                ["%all-reduce.2", 1300, 30], ["%fusion.1", 2000, 100],
+                ["%all-reduce-start.4", 2150, 10 * (i + 1)],
+                ["%all-reduce-done.4", 2200, 5],
+                ["%all-gather.1", 2550, 40],
+                ["%all-reduce.9", 2700, 50]]}
+        for i in range(2)},
+    "host": [["window", 900, 1900]],
+}
+
+
+def test_collective_time_per_module_on_a_two_chip_trace():
+    """The all-reduce and all-gather ops inside each module's calls,
+    averaged over the two device planes; ops outside every call (at 2700,
+    after the last call ends) are not counted."""
+    from bench import harness, trace
+    r = trace.reduce(TWO_CHIPS)
+    per_dev = [20 + i + 30 + 10 * (i + 1) + 5 for i in range(2)]
+    assert r["collectives"] == pytest.approx({
+        "jit__fused_batch": sum(per_dev) / 2 * 1e-9, "jit__other": 40e-9})
+    assert trace.module_time(r, "jit__fused_batch") == pytest.approx(
+        (700e-9, 2))
+    assert r["busy_s"] == pytest.approx(610e-9)
+    read = harness.load_module(ROOT / "bench/metrics/allreduce_ms.x4.py").read
+    assert read(SimpleNamespace(trace=r)) == pytest.approx(
+        sum(per_dev) / 2 / 2 * 1e-6)
+    assert read(SimpleNamespace(trace=trace.reduce(HAND_TRACE))) is None
+    # the one-chip cell's readers serve the four-chip cell as they are
+    rec = SimpleNamespace(trace=r)
+    rollout = harness.load_module(ROOT / "bench/metrics/rollout_ms.unique.py")
+    idle = harness.load_module(ROOT / "bench/metrics/device_idle.unique.py")
+    assert rollout.read(rec) == pytest.approx(350e-6)
+    assert idle.read(rec) == pytest.approx(100 * (1 - 610 / 1900))
+
+
 @pytest.mark.parametrize("name", sorted(
     p.name for p in (ROOT / "bench" / "tests").glob("trace_*.json")))
 def test_trace_reduction_on_a_recorded_chip_trace(name):
@@ -146,6 +234,7 @@ def test_trace_reduction_on_a_recorded_chip_trace(name):
         assert r["modules"][mod] == pytest.approx([secs, calls], rel=1e-9)
     assert sum(v for _, v in r["idle_gaps"]) == pytest.approx(
         r["window_s"] - r["busy_s"], rel=1e-9)
+    assert r["collectives"] == {}       # one chip: no collective
 
 
 def test_run_exits_non_zero_without_a_tpu():
@@ -224,3 +313,31 @@ def test_reference_environment_and_model_match_the_program_on_cpu():
     theirs = dt_apply(params, DTConfig(max_steps=T, hw_dim=10), *args[:3],
                       hw=args[3])
     np.testing.assert_allclose(ours, theirs, rtol=1e-4, atol=1e-5)
+
+
+def test_seeds_reorder_one_mapper():
+    """Two seeds' weights differ leaf by leaf and compute the same
+    decision transformer to f32 round-off, so every seed gives the cell
+    the same work."""
+    import jax
+    import jax.numpy as jnp
+    from bench import harness
+    from repro import DTConfig
+    from repro.core.model import dt_apply
+    model = dict(n_blocks=3, n_heads=2, d_model=128, d_ff=512, max_steps=16,
+                 hw_dim=10)
+    a = harness.dt_weights(model, 2 ** 31 + 7)
+    b = harness.dt_weights(model, 5)
+    la, lb = jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)
+    assert [x.shape for x in la] == [x.shape for x in lb]
+    # every leaf but the head's constant bias is reordered
+    assert sum(not np.array_equal(x, y) for x, y in zip(la, lb)) == len(la) - 1
+    rng = np.random.default_rng(0)
+    args = [jnp.asarray(rng.normal(size=s), jnp.float32)
+            for s in ((4, 16), (4, 16, 8), (4, 16), (4, 10))]
+    cfg = DTConfig(max_steps=16, hw_dim=10)
+    with jax.default_matmul_precision("highest"):
+        ya = dt_apply(a, cfg, *args[:3], hw=args[3])
+        yb = dt_apply(b, cfg, *args[:3], hw=args[3])
+    assert float(jnp.std(ya)) > 0.05
+    np.testing.assert_allclose(ya, yb, rtol=1e-4, atol=1e-5)

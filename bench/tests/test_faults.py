@@ -3,13 +3,19 @@ sound run passes its limits; the control (the reference one precision
 below the configuration's, in the program's place) fails one of them; and
 a run with the timed path broken underneath fails, once for each fault a
 cell can have: a token altered where it is produced, an answer altered
-where it is produced."""
+where it is produced, and, in the four-chip cell, the exchange between
+chips left out."""
 from __future__ import annotations
+
+import json
+import os
+import subprocess
+import sys
 
 import numpy as np
 import pytest
 
-from conftest import small_spec
+from conftest import ROOT, small_spec
 
 
 def within(numbers: dict, kind: str) -> bool:
@@ -25,17 +31,17 @@ def test_a_sound_run_is_correct(cpu_run, kind):
 
 def test_the_control_fails(cpu_run):
     from bench import check
-    from bench.reference import BF16
+    spec = small_spec("closed")
     _, numbers, rec = cpu_run("closed")
-    limits = small_spec("closed").config["limits"]
-    control = check.control_served(rec.sample, rec.params, rec.model,
-                                   limits)
+    control = check.control_served(spec.reference, rec.sample, rec.params,
+                                   rec.model, spec.config["limits"])
     assert within(numbers, "closed") and not within(control, "closed"), \
         control
+    spec = small_spec("search")
     _, numbers, rec = cpu_run("search")
-    control = check.check_search(rec.sample,
-                                 small_spec("search").config["limits"],
-                                 dt=BF16)
+    control = check.check_search(spec.reference, rec.sample,
+                                 spec.config["limits"],
+                                 dt=spec.reference.BF16)
     assert not within(control, "search"), control
 
 
@@ -83,3 +89,22 @@ def test_a_broken_search_is_caught(cpu_run, monkeypatch, field):
     monkeypatch.setattr(gsampler, "_ga_grid", broken)
     _, numbers, _ = cpu_run("search")
     assert not within(numbers, "search"), numbers
+
+
+def test_the_four_chip_cell_on_four_virtual_devices():
+    """``dt-unique-closed-4chip`` cut to CPU size on four virtual devices,
+    in a process of its own: four replicas serve it correctly, and leaving
+    out the exchange between chips is caught."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    p = subprocess.run([sys.executable, "bench/tests/four_chips.py"],
+                       cwd=ROOT, env=env, capture_output=True, text=True,
+                       timeout=900)
+    assert p.returncode == 0, p.stderr[-4000:]
+    runs = {r["run"]: r for r in (json.loads(ln) for ln in
+                                  p.stdout.splitlines()
+                                  if ln.startswith("{"))}
+    sound, broken = runs["sound"], runs["no_exchange"]
+    assert sound["replicas"] == broken["replicas"] == 4
+    assert sound["attempted"] > 0 and sound["correct"], sound
+    assert not broken["correct"], broken

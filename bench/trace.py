@@ -8,11 +8,16 @@ also the form of the recorded fixture the tests check ``reduce`` on.
 """
 from __future__ import annotations
 
+import bisect
 import glob
 import os
 import re
 
 HOST_SPANS = ("generate", "submit", "pump", "search_call", "window")
+# XLA's cross-device ops, by the start of their op name (with the async
+# forms' ``-start`` and ``-done`` halves)
+COLLECTIVES = re.compile(r"%?(all-reduce|all-gather|reduce-scatter|"
+                         r"collective-permute|all-to-all)")
 
 
 def load(trace_dir: str):
@@ -84,14 +89,17 @@ def window_of(events: dict) -> tuple[float, float]:
 def reduce(events: dict, top: int = 10) -> dict:
     """Device busy and idle time over the traced window, device time per
     XLA module (seconds and event count, averaged over devices), the
-    device ops that took most time, and idle time by the host span that
-    overlapped each idle gap most (``other`` where none did)."""
+    device time of the collective ops inside each module's calls
+    (``collectives``, averaged over devices, over the same calls as
+    ``modules``), the device ops that took most time, and idle time by the
+    host span that overlapped each idle gap most (``other`` where none
+    did)."""
     t0, t1 = window_of(events)
     window_s = (t1 - t0) / 1e9
     devs = events["devices"]
     if not devs:
         raise ValueError("the trace holds no device plane")
-    busy, modules, ops = [], {}, {}
+    busy, modules, ops, collectives = [], {}, {}, {}
     gaps: list = []
     for dev in devs.values():
         evs = dev["ops"] or dev["modules"]
@@ -105,6 +113,18 @@ def reduce(events: dict, top: int = 10) -> dict:
                 m = modules.setdefault(module_name(name), [0.0, 0])
                 m[0] += d / 1e9 / len(devs)
                 m[1] += 1 / len(devs)
+        calls = sorted((s, s + d, module_name(name))
+                       for name, s, d in dev["modules"]
+                       if t0 <= s and s + d <= t1)
+        starts = [s for s, _, _ in calls]
+        for name, s, d in dev["ops"]:
+            if not COLLECTIVES.match(name):
+                continue
+            i = bisect.bisect_right(starts, s) - 1
+            if i >= 0 and s + d <= calls[i][1]:
+                key = calls[i][2]
+                collectives[key] = (collectives.get(key, 0.0)
+                                    + d / 1e9 / len(devs))
         for name, s, d in dev["ops"]:
             lo, hi = max(s, t0), min(s + d, t1)
             if hi > lo:
@@ -125,6 +145,7 @@ def reduce(events: dict, top: int = 10) -> dict:
         "busy_s": busy_s,
         "idle_share": 1.0 - busy_s / window_s,
         "modules": modules,
+        "collectives": collectives,
         "device_ops": sorted(([k, v] for k, v in ops.items()),
                              key=lambda kv: -kv[1])[:top],
         "idle_gaps": sorted(([k, v] for k, v in idle.items()),
