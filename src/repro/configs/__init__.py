@@ -2,10 +2,13 @@
 
 Each ``<arch>.py`` defines ``CONFIG`` with the exact published parameters;
 ``get_config(name)`` returns it, ``get_config(name, reduced=True)`` returns
-the same-family smoke-test reduction.  ``SHAPES`` is the assigned input-
-shape grid; ``cells()`` enumerates the (arch x shape) dry-run cells with the
-DESIGN §5 long_500k skip policy applied.
+the same-family smoke-test reduction.  ``MAPPED_NAMES`` are configs only
+the fusion mapper lowers (no LM substrate runs them).  ``SHAPES`` is the
+assigned input-shape grid; ``cells()`` enumerates the (arch x shape)
+dry-run cells with the DESIGN §5 long_500k skip policy applied.
 """
-from .base import ArchConfig, Shape, SHAPES, ARCH_NAMES, get_config, cells
+from .base import (ArchConfig, Shape, SHAPES, ARCH_NAMES, MAPPED_NAMES,
+                   get_config, cells)
 
-__all__ = ["ArchConfig", "Shape", "SHAPES", "ARCH_NAMES", "get_config", "cells"]
+__all__ = ["ArchConfig", "Shape", "SHAPES", "ARCH_NAMES", "MAPPED_NAMES",
+           "get_config", "cells"]

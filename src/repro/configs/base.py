@@ -4,7 +4,8 @@ from __future__ import annotations
 import importlib
 from dataclasses import dataclass, field, replace
 
-__all__ = ["ArchConfig", "Shape", "SHAPES", "ARCH_NAMES", "get_config", "cells"]
+__all__ = ["ArchConfig", "Shape", "SHAPES", "ARCH_NAMES", "MAPPED_NAMES",
+           "get_config", "cells"]
 
 
 def _round_up(x: int, m: int) -> int:
@@ -35,6 +36,24 @@ class ArchConfig:
     n_experts: int = 0
     moe_top_k: int = 0
     capacity_factor: float = 1.25
+    # DeepSeek-style MoE: d_ff is the dense layers' width, moe_d_ff each
+    # expert's; the first ``first_k_dense_replace`` layers are dense;
+    # ``experts_held`` of the n_experts routed experts live on one chip
+    # under expert parallelism (0 = all of them)
+    moe_d_ff: int = 0
+    n_shared_experts: int = 0
+    first_k_dense_replace: int = 0
+    experts_held: int = 0
+    # multi-head latent attention (MLA): low-rank query and key/value
+    # projections, per-head dims of the non-rotary and rotary query/key
+    # parts and of the value; 0 = standard attention
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # multi-token prediction modules after the last layer
+    mtp_layers: int = 0
     # SSM / hybrid
     ssm_state: int = 0
     ssm_conv: int = 4
@@ -140,13 +159,18 @@ ARCH_NAMES = [
     "grok1_314b", "qwen3_moe_235b", "rwkv6_3b", "qwen2_vl_72b", "hymba_15b",
 ]
 
-_ALIASES = {n.replace("_", "-"): n for n in ARCH_NAMES}
+# Configs the fusion mapper lowers (``workloads.lm_workloads``) but the LM
+# substrate cannot run: it has no latent attention.
+MAPPED_NAMES = ["deepseek_v3"]
+
+_ALIASES = {n.replace("_", "-"): n for n in ARCH_NAMES + MAPPED_NAMES}
 
 
 def get_config(name: str, reduced: bool = False) -> ArchConfig:
     key = _ALIASES.get(name, name)
-    if key not in ARCH_NAMES:
-        raise KeyError(f"unknown arch {name!r}; have {ARCH_NAMES}")
+    if key not in ARCH_NAMES + MAPPED_NAMES:
+        raise KeyError(f"unknown arch {name!r}; have "
+                       f"{ARCH_NAMES + MAPPED_NAMES}")
     cfg = importlib.import_module(f"repro.configs.{key}").CONFIG
     return cfg.reduced() if reduced else cfg
 

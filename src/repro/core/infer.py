@@ -57,6 +57,7 @@ class InferResult:
     wall_s: float
     n_model_calls: int
     guard_iters: int         # the budget guard's halvings and syncs
+    guard_syncs: int         # steps whose micro-batch the guard made SYNC
 
 
 @partial(jax.jit, static_argnames=("cfg", "backend"))
@@ -83,7 +84,7 @@ def _rollout(backend, params, cfg, env: FusionEnv, *,
     hwf = _hw_condition(cfg, env)
     t0 = time.perf_counter()
     s = env.reset()
-    calls = guard_iters = 0
+    calls = guard_iters = guard_syncs = 0
     for t in range(env.n + 1):
         states[0, t] = s
         rtg[0, t] = env.reward_to_go
@@ -108,6 +109,7 @@ def _rollout(backend, params, cfg, env: FusionEnv, *,
                     break
                 a = a // 2 if a > 1 else cm.SYNC
                 guard_iters += 1
+            guard_syncs += int(a == cm.SYNC)
         actions[0, t] = encode_action(np.float32(a), env.batch)
         s, _, done = env.step(a)
     wall = time.perf_counter() - t0
@@ -115,7 +117,8 @@ def _rollout(backend, params, cfg, env: FusionEnv, *,
     out = env.evaluate_strategy(strat)
     return InferResult(strat, env.baseline_latency / float(out.latency),
                        float(out.latency), float(out.peak_mem),
-                       bool(out.valid), wall, calls, guard_iters)
+                       bool(out.valid), wall, calls, guard_iters,
+                       guard_syncs)
 
 
 def dnnfuser_infer(params, cfg, env: FusionEnv, *,
@@ -175,7 +178,7 @@ def _fused_episode(params, cfg, wl, batch, budget_bytes, hw,
         actions = jnp.full((P,), cm.SYNC, jnp.int32).at[0].set(a0)
 
     def step(sc, t):
-        carry, mstate, a_prev, actions, iters = sc
+        carry, mstate, a_prev, actions, iters, syncs = sc
         active = t <= n
         with jax.named_scope("env_step"):
             r_t, s_t = env_observe(consts, carry, hw)
@@ -187,23 +190,28 @@ def _fused_episode(params, cfg, wl, batch, budget_bytes, hw,
             a = decode_action_jnp(pred[0], B)
         if repair:
             with jax.named_scope("guard"):
+                proposed = a
                 a, k = guard(carry, a)
                 iters = iters + jnp.where(active, k, 0)
+                syncs = syncs + (active & (proposed >= 1)
+                                 & (a == cm.SYNC)).astype(jnp.int32)
         with jax.named_scope("env_step"):
             a = jnp.where(active, a, jnp.int32(cm.SYNC))
             new_carry = env_step(consts, carry, a, hw)
             carry = cm._tree_select(active, new_carry, carry)
             actions = actions.at[t].set(a)
             a_prev = jnp.where(active, a, a_prev)
-        return (carry, mstate, a_prev, actions, iters), None
+        return (carry, mstate, a_prev, actions, iters, syncs), None
 
-    (carry, _, _, actions, iters), _ = jax.lax.scan(
-        step, (carry, mstate, a0, actions, jnp.int32(0)), jnp.arange(1, P))
+    (carry, _, _, actions, iters, syncs), _ = jax.lax.scan(
+        step, (carry, mstate, a0, actions, jnp.int32(0), jnp.int32(0)),
+        jnp.arange(1, P))
     out = env_final(consts, carry, hw)
     return dict(strategy=actions, latency=out.latency,
                 peak_mem=out.peak_mem, valid=out.valid,
                 speedup=consts.base_lat / jnp.maximum(out.latency, 1e-12),
-                baseline_latency=consts.base_lat, guard_iters=iters)
+                baseline_latency=consts.base_lat, guard_iters=iters,
+                guard_syncs=syncs)
 
 
 @partial(jax.jit, static_argnames=("cfg", "repair", "backend"))
@@ -237,7 +245,8 @@ def _fused_infer(backend, params, cfg, env: FusionEnv, repair) -> InferResult:
     wall = time.perf_counter() - t0
     return InferResult(strat, float(out["speedup"]), float(out["latency"]),
                        float(out["peak_mem"]), bool(out["valid"]), wall,
-                       env.n + 1, int(out["guard_iters"]))
+                       env.n + 1, int(out["guard_iters"]),
+                       int(out["guard_syncs"]))
 
 
 def dnnfuser_infer_fused(params, cfg, env: FusionEnv, *,
@@ -275,8 +284,10 @@ def dnnfuser_infer_batch(params, cfg, env_or_wl, batches,
     heterogeneous per-row accelerators serve in the same fused call
     (DESIGN §11).  Any registered ``MapperBackend`` config works (DT and
     seq2seq).  Returns a dict of stacked arrays (strategy [C, P] int32,
-    latency/peak_mem/speedup/valid [C], and guard_iters [C]: the budget
-    guard's halvings and syncs over each row's true steps)."""
+    latency/peak_mem/speedup/valid [C], guard_iters [C]: the budget
+    guard's halvings and syncs over each row's true steps, and guard_syncs
+    [C]: the steps among them whose proposed micro-batch the guard ended
+    at SYNC)."""
     if isinstance(env_or_wl, FusionEnv):
         wl = env_or_wl.wl
         if hw is None:

@@ -221,6 +221,7 @@ class MapperEngine:
         self.coalesce_hist: dict[int, int] = {}  # true chunk width -> count
         self.ticks = 0                           # serve() calls
         self.guard_iters = 0                     # guard halvings + syncs
+        self.guard_syncs = 0                     # steps the guard synced
         self.rollout_steps = 0                   # true steps (n + 1) rolled
         self.spans: dict = {}                    # obs.span tallies
         # -- §17 propose-then-polish accounting --
@@ -411,6 +412,7 @@ class MapperEngine:
             self.device_calls += 1
             self.coalesce_hist[C] = self.coalesce_hist.get(C, 0) + 1
             self.guard_iters += int(res["guard_iters"][:C].sum())
+            self.guard_syncs += int(res["guard_syncs"][:C].sum())
             self.rollout_steps += sum(r.workload.n + 1 for _, r, _ in group)
             if self.polish or self.escalate:
                 self._refine_chunk(res, group, wl,
@@ -683,6 +685,7 @@ class MapperEngine:
             "coalesce_width_hist": dict(sorted(self.coalesce_hist.items())),
             "ticks": self.ticks,
             "guard_iters": self.guard_iters,
+            "guard_syncs": self.guard_syncs,
             "rollout_steps": self.rollout_steps,
             "spans": {k: dict(v) for k, v in self.spans.items()},
             "packed_workloads": len(self._packed),

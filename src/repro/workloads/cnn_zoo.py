@@ -13,7 +13,7 @@ from __future__ import annotations
 from .layer import Layer, Workload
 
 __all__ = ["vgg16", "resnet18", "resnet50", "mobilenet_v2", "mnasnet_b1",
-           "tiny_cnn", "CNN_ZOO", "get_workload"]
+           "tiny_cnn", "CNN_ZOO"]
 
 
 class _ChainBuilder:
@@ -172,8 +172,3 @@ CNN_ZOO = {
     "mnasnet": mnasnet_b1,
 }
 
-
-def get_workload(name: str, batch: int = 64) -> Workload:
-    if name not in CNN_ZOO:
-        raise KeyError(f"unknown workload {name!r}; have {sorted(CNN_ZOO)}")
-    return CNN_ZOO[name](batch)

@@ -177,6 +177,31 @@ def test_guard_iters_count_the_host_guards_halvings_and_syncs():
     assert out["guard_iters"][:3].min() > 0     # the guard did work
 
 
+def test_guard_syncs_count_the_host_guards_forced_syncs():
+    """``guard_syncs`` counts, lane by lane, the steps whose proposed
+    micro-batch the host guard ended at SYNC; each is also one of the
+    ``guard_iters``."""
+    wl = vgg16()
+    params = _biased(dt_init(jax.random.PRNGKey(0), CFG), 0.9)
+    batches = np.array([64.0, 32.0, 16.0, 64.0], np.float32)
+    budgets = np.array([4.0, 6.0, 10.0, 64.0], np.float32) * MB
+    env0 = FusionEnv(wl, HW, batch=64, budget_bytes=32 * MB,
+                     nmax=CFG.max_steps)
+    out = dnnfuser_infer_batch(params, CFG, env0, batches, budgets)
+    off = dnnfuser_infer_batch(params, CFG, env0, batches, budgets,
+                               repair=False)
+    assert (off["guard_syncs"] == 0).all()
+    assert (out["guard_syncs"] <= out["guard_iters"]).all()
+    for i in range(len(batches)):
+        env = FusionEnv(wl, HW, batch=int(batches[i]),
+                        budget_bytes=float(budgets[i]), nmax=CFG.max_steps)
+        h = dnnfuser_infer(params, CFG, env, repair=True)
+        assert int(out["guard_syncs"][i]) == h.guard_syncs, i
+        f = dnnfuser_infer_fused(params, CFG, env, repair=True)
+        assert f.guard_syncs == h.guard_syncs
+    assert out["guard_syncs"][0] > 0            # the 4 MB lane is forced
+
+
 def test_infer_batch_matches_single_condition_runs():
     wl = resnet18()
     params = dt_init(jax.random.PRNGKey(2), CFG)

@@ -314,7 +314,8 @@ def test_engine_stats_schema():
                 "coalesce_width_hist", "strategy_hit_rate", "strategy_cache",
                 "replicas", "scheduler", "drift",
                 "escalations", "polish_invocations", "polish_improved",
-                "ticks", "guard_iters", "rollout_steps", "spans"):
+                "ticks", "guard_iters", "guard_syncs", "rollout_steps",
+                "spans"):
         assert key in s, key
     # host spans: one tick, one device call in four phases
     spans = s["spans"]
@@ -325,7 +326,7 @@ def test_engine_stats_schema():
     assert spans["engine.serve"]["count"] == s["ticks"] == 1
     assert "engine.warmup" not in spans            # never warmed
     assert s["rollout_steps"] == vgg16().n + 1
-    assert s["guard_iters"] >= 0
+    assert s["guard_iters"] >= s["guard_syncs"] >= 0
     # §17 refinement is off by default: counters exist but never move
     assert (s["escalations"], s["polish_invocations"],
             s["polish_improved"]) == (0, 0, 0)

@@ -28,7 +28,7 @@ from repro.core.env import STATE_DIM
 from repro.core.model import DTConfig, dt_init, dt_loss
 from repro.core.train import make_train_step
 from repro.kernels import fusion_eval
-from repro.workloads import resnet50
+from repro.workloads import get_workload, resnet50
 
 CFG = DTConfig(hw_dim=HW_FEATURE_DIM)
 
@@ -113,6 +113,28 @@ def test_engine_rollout_compiles(one_chip):
                                sharding=one_chip)
     compiled = infer._fused_batch.lower(
         params, CFG, wl, vec, vec, hw, hwf, True, backend_for(CFG),
+        True).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2 ** 30
+
+
+def test_engine_rollout_compiles_at_128_steps(one_chip):
+    """The rollout of a 128-step mapper in its nmax-128 bucket: a 16-lane
+    tick of DeepSeek-V3 prefill chains (126 positions)."""
+    lanes, nmax = 16, 128
+    cfg = DTConfig(hw_dim=HW_FEATURE_DIM, max_steps=nmax)
+    params = _sds(jax.eval_shape(lambda k: dt_init(k, cfg),
+                                 jax.random.PRNGKey(0)), one_chip)
+    packed = cm.pack_workload(get_workload("deepseek_v3_prefill_32k"),
+                              ACCEL_ZOO["datacenter"], nmax)
+    wl = {k: jax.ShapeDtypeStruct((lanes,) + v.shape, v.dtype,
+                                  sharding=one_chip)
+          for k, v in packed.items()}
+    vec = jax.ShapeDtypeStruct((lanes,), jnp.float32, sharding=one_chip)
+    hw = HwVec(*([vec] * HW_FEATURE_DIM))
+    hwf = jax.ShapeDtypeStruct((lanes, HW_FEATURE_DIM), jnp.float32,
+                               sharding=one_chip)
+    compiled = infer._fused_batch.lower(
+        params, cfg, wl, vec, vec, hw, hwf, True, backend_for(cfg),
         True).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2 ** 30
 
